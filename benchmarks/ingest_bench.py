@@ -29,9 +29,10 @@ A **block_skip sweep** measures the second pruning level: selective range
 predicates over a clustered (sorted, unindexed) column, with bind-time
 block zone-map skipping on vs. off — latency plus blocks touched, which
 must scale with the predicate's block footprint, not the dataset. A
-**block_skip_sharded sweep** repeats the cell over an 8-way simulated host
-mesh (subprocess with forced device count): zone maps are laid out per row
-partition and each shard's kernel grid scans only its own survivors.
+**block_skip_sharded sweep** repeats the cell over an 8-device mesh of
+this process (on the CPU: ``XLA_FLAGS=--xla_force_host_platform_device_count=8``
+on the command line): zone maps are laid out per row partition and each
+shard's kernel grid scans only its own survivors.
 
 A **concurrent-serving sweep** replays the stream with a reader thread
 (its own Session on the SHARED catalog) hammering an indexed range count
@@ -300,94 +301,71 @@ def _string_predicate_sweep(size: str, repeats: int = 5) -> list[dict]:
 def _block_skip_sharded_sweep(size: str, repeats: int = 5,
                               devices: int = 8) -> list[dict]:
     """Multi-shard variant of the block-skip sweep: the same clustered
-    dataset laid out over an ``devices``-way simulated host mesh, where the
-    zone maps are harvested per row partition and each shard's kernel grid
-    scans only its own surviving blocks. jax locks the process device count
-    at first init, so the cell runs in a fresh interpreter with forced host
-    devices (the tests' subprocess pattern) and reports back as JSON."""
-    import os
-    import subprocess
-    import sys
+    dataset laid out over a ``devices``-way mesh of this process's first
+    devices (raises when fewer exist — on the CPU, provide them with
+    ``XLA_FLAGS=--xla_force_host_platform_device_count=8``), where the zone
+    maps are harvested per row partition and each shard's kernel grid
+    scans only its own surviving blocks."""
+    from repro.engine.table import Table
+    from repro.launch.mesh import make_local_mesh
+    from repro.runtime import telemetry as tel
 
     base_rows, _, _ = SIZES[size]
     n = max(base_rows, devices * 4096)
     n -= n % devices  # even row partitions -> the sharded zone-map layout
-    body = f"""
-import json, time
-import numpy as np
-from repro.core.frame import AFrame
-from repro.engine.session import Session
-from repro.engine.table import Table
-from repro.launch.mesh import make_local_mesh
-from repro.runtime import telemetry as tel
-
-n, repeats, devices = {n}, {repeats}, {devices}
-ids = np.arange(n, dtype=np.int32)
-rng = np.random.default_rng(11)
-sess = Session(mesh=make_local_mesh(data=devices, model=1), mode="kernel",
-               enable_index=False)
-sess.create_dataset("Clustered",
-                    Table({{"id": ids, "ts": ids.copy(),
-                            "val": rng.integers(0, 100, n).astype(np.int32)}}),
-                    dataverse="bench", primary="id")
-df = AFrame("bench", "Clustered", session=sess)
-bz = sess.catalog.get("bench", "Clustered").block_zones
-n_blocks = bz.n_blocks
-cells = []
-for label, span_blocks in (("1-block", 1),
-                           ("10pct", max(n_blocks // 10, 1)),
-                           ("50pct", max(n_blocks // 2, 1))):
-    lo = 4096
-    hi = min(lo + span_blocks * 4096 - 1, n - 1)
-    cell = {{"size": {size!r}, "variant": "block_skip_sharded",
-             "selectivity": label, "n_rows": n, "shards": devices,
-             "blocks_total": n_blocks}}
-    for skip in (True, False):
-        sess.enable_block_skip = skip
-        tag = "skipped" if skip else "unskipped"
-        want = hi - lo + 1
-        got = len(df[(df["ts"] >= lo) & (df["ts"] <= hi)])  # warm/compile
-        assert got == want, (got, want)
-        times = []
-        for _ in range(repeats):
-            t0 = time.perf_counter()
-            len(df[(df["ts"] >= lo) & (df["ts"] <= hi)])
-            times.append(time.perf_counter() - t0)
-        rep = sess.last_prune_report
-        cell[tag] = {{
-            "query_median_s": round(float(np.median(times)), 5),
-            "blocks_scanned": int(rep["blocks_scanned"]),
-            "blocks_skipped": int(rep["blocks_skipped"]),
-        }}
-    sess.enable_block_skip = True
-    s, u = cell["skipped"], cell["unskipped"]
-    cell["query_speedup"] = round(
-        u["query_median_s"] / max(s["query_median_s"], 1e-9), 2)
-    cells.append(cell)
-cells.append({{"size": {size!r}, "variant": "block_skip_sharded:telemetry",
-               "blocks_skipped_total": int(tel.counter_value(
-                   "kernel.blocks_skipped_total", kernel="filter_count")
-                   or 0)}})
-print("CELLS=" + json.dumps(cells))
-"""
-    env = dict(os.environ)
-    env["XLA_FLAGS"] = f"--xla_force_host_platform_device_count={devices}"
-    env["PYTHONPATH"] = str(pathlib.Path(__file__).resolve().parents[1] / "src")
-    r = subprocess.run([sys.executable, "-c", body], capture_output=True,
-                       text=True, timeout=900, env=env)
-    assert r.returncode == 0, (
-        f"sharded block-skip cell failed:\n{r.stdout}\n{r.stderr[-3000:]}")
-    line = [l for l in r.stdout.splitlines() if l.startswith("CELLS=")][-1]
-    cells = json.loads(line[len("CELLS="):])
-    for c in cells:
-        if "skipped" not in c:
-            continue
-        s, u = c["skipped"], c["unskipped"]
-        print(f"  {size:>2} block_skip_sharded {c['selectivity']:<8} "
-              f"({c['shards']} shards) blocks {u['blocks_scanned']} -> "
-              f"{s['blocks_scanned']} of {c['blocks_total']}  query "
+    ids = np.arange(n, dtype=np.int32)
+    rng = np.random.default_rng(11)
+    sess = Session(mesh=make_local_mesh(data=devices, model=1),
+                   mode="kernel", enable_index=False)
+    sess.create_dataset(
+        "Clustered",
+        Table({"id": ids, "ts": ids.copy(),
+               "val": rng.integers(0, 100, n).astype(np.int32)}),
+        dataverse="bench", primary="id")
+    df = AFrame("bench", "Clustered", session=sess)
+    n_blocks = sess.catalog.get("bench", "Clustered").block_zones.n_blocks
+    skipped_before = tel.counter_value("kernel.blocks_skipped_total",
+                                       kernel="filter_count") or 0
+    cells = []
+    for label, span_blocks in (("1-block", 1),
+                               ("10pct", max(n_blocks // 10, 1)),
+                               ("50pct", max(n_blocks // 2, 1))):
+        lo = 4096
+        hi = min(lo + span_blocks * 4096 - 1, n - 1)
+        cell = {"size": size, "variant": "block_skip_sharded",
+                "selectivity": label, "n_rows": n, "shards": devices,
+                "blocks_total": n_blocks}
+        for skip in (True, False):
+            sess.enable_block_skip = skip
+            tag = "skipped" if skip else "unskipped"
+            want = hi - lo + 1
+            got = len(df[(df["ts"] >= lo) & (df["ts"] <= hi)])  # warm/compile
+            assert got == want, (got, want)
+            times = []
+            for _ in range(repeats):
+                t0 = time.perf_counter()
+                len(df[(df["ts"] >= lo) & (df["ts"] <= hi)])
+                times.append(time.perf_counter() - t0)
+            rep = sess.last_prune_report
+            cell[tag] = {
+                "query_median_s": round(float(np.median(times)), 5),
+                "blocks_scanned": int(rep["blocks_scanned"]),
+                "blocks_skipped": int(rep["blocks_skipped"]),
+            }
+        sess.enable_block_skip = True
+        s, u = cell["skipped"], cell["unskipped"]
+        cell["query_speedup"] = round(
+            u["query_median_s"] / max(s["query_median_s"], 1e-9), 2)
+        cells.append(cell)
+        print(f"  {size:>2} block_skip_sharded {label:<8} "
+              f"({devices} shards) blocks {u['blocks_scanned']} -> "
+              f"{s['blocks_scanned']} of {n_blocks}  query "
               f"{u['query_median_s']*1e3:.2f} -> "
-              f"{s['query_median_s']*1e3:.2f} ms ({c['query_speedup']}x)")
+              f"{s['query_median_s']*1e3:.2f} ms ({cell['query_speedup']}x)")
+    skipped = (tel.counter_value("kernel.blocks_skipped_total",
+                                 kernel="filter_count") or 0) - skipped_before
+    cells.append({"size": size, "variant": "block_skip_sharded:telemetry",
+                  "blocks_skipped_total": int(skipped)})
     return cells
 
 

@@ -1,14 +1,17 @@
 """Benchmark orchestrator — one entry per paper table/figure.
 
   --single-node : Fig. 8-11 / Tables V-VI (12 expressions × variants × sizes)
-  --scaling     : Tables VII-VIII (speedup / scaleup via subprocess shards)
+  --scaling     : Tables VII-VIII (speedup / scaleup over 1..8 devices)
   --model       : Fig. 5/6 analogue (model-UDF / serve / train rates)
   --roofline    : §Roofline table from the dry-run artifacts
   --ingest      : streaming ingestion (deferred compaction vs
                   compact-every-flush rows/sec + query freshness)
   (no flags)    : quick versions of all of the above
 
-Outputs land in results/bench/.
+Outputs land in results/bench/. Everything runs in this one process;
+``--scaling`` and ``--ingest`` build meshes of up to 8 devices, which on the
+CPU come from ``XLA_FLAGS=--xla_force_host_platform_device_count=8`` on the
+command line.
 """
 from __future__ import annotations
 
@@ -85,7 +88,7 @@ def main() -> None:
     if args.scaling or run_all:
         from benchmarks.scaling_bench import run_scaling
 
-        print("== speedup / scaleup (subprocess shards) ==")
+        print("== speedup / scaleup (meshes over this process's devices) ==")
         run_scaling(OUT / "scaling.json", quick=not args.full)
 
     if args.model or run_all:

@@ -1,65 +1,56 @@
 """Speedup / scaleup (paper §IV-D2, Tables VII/VIII).
 
-Each (shards, rows) point runs in a FRESH subprocess with
-``--xla_force_host_platform_device_count=<shards>`` so the shard_map engine
-partitions exactly as it would across machines.
+Every (shards, rows) point runs in THIS process, on a mesh over the first
+``shards`` devices of ``jax.devices()``; a point that asks for more devices
+than exist raises. One process owns every device, so on an accelerator
+host no child ever competes for a chip. On the CPU the caller provides host
+devices on the command line::
 
-CPU-container caveat (recorded in EXPERIMENTS.md): one physical core executes
-all shards, so wall-clock cannot show hardware speedup — what these curves
-measure is the *distribution overhead structure* (per-shard work + collective
-emulation), i.e. the flat-or-gently-rising scaleup line and the
-overhead-dominated speedup line one expects from emulated shards.
+    XLA_FLAGS=--xla_force_host_platform_device_count=8 \\
+        PYTHONPATH=src python -m benchmarks.run --scaling
+
+There one physical core executes all shards, so wall-clock cannot show
+hardware speedup — the curves then measure the *distribution overhead
+structure* (per-shard work + collective emulation) only.
 """
 from __future__ import annotations
 
 import json
-import os
 import pathlib
-import subprocess
-import sys
+import time
 
-ROOT = pathlib.Path(__file__).resolve().parents[1]
-
-_CHILD = r"""
-import json, sys, time
 import numpy as np
-shards, rows = int(sys.argv[1]), int(sys.argv[2])
-from repro.data import wisconsin
-from repro.engine.session import Session
-from repro.core.frame import AFrame
-from repro.launch.mesh import make_local_mesh
-from benchmarks.wisconsin_bench import EXPRESSIONS, AFrameVariant, WARMUP, RUNS
-
-mesh = make_local_mesh(data=shards, model=1) if shards > 1 else None
-sess = Session(mesh=mesh, mode="shard_map" if shards > 1 else "gspmd")
-table = wisconsin.generate(rows, seed=11)
-sess.create_dataset("data", table, dataverse="bench", closed=True,
-                    indexes=["onePercent", "unique1"], primary="unique2")
-sess.create_dataset("data_r", table, dataverse="bench", closed=True,
-                    indexes=["onePercent", "unique1"], primary="unique2")
-v = AFrameVariant("aframe-index", sess, "data")
-t0 = time.perf_counter(); v.create(); creation = time.perf_counter() - t0
-out = {}
-for name, fn in EXPRESSIONS:
-    rng = np.random.default_rng(5)
-    ts = []
-    for _ in range(WARMUP + RUNS):
-        t0 = time.perf_counter(); fn(v, rng, rows); ts.append(time.perf_counter() - t0)
-    out[name] = float(np.mean(ts[WARMUP:]))
-print(json.dumps({"shards": shards, "rows": rows, "creation_s": creation,
-                  "expr_s": out}))
-"""
 
 
-def run_point(shards: int, rows: int, timeout: int = 560) -> dict:
-    env = dict(os.environ)
-    env["XLA_FLAGS"] = f"--xla_force_host_platform_device_count={max(shards, 1)}"
-    env["PYTHONPATH"] = f"{ROOT / 'src'}:{ROOT}"
-    r = subprocess.run([sys.executable, "-c", _CHILD, str(shards), str(rows)],
-                       capture_output=True, text=True, timeout=timeout, env=env)
-    if r.returncode != 0:
-        raise RuntimeError(r.stderr[-2000:])
-    return json.loads(r.stdout.strip().splitlines()[-1])
+def run_point(shards: int, rows: int) -> dict:
+    from benchmarks.wisconsin_bench import (EXPRESSIONS, RUNS, WARMUP,
+                                            AFrameVariant)
+    from repro.data import wisconsin
+    from repro.engine.session import Session
+    from repro.launch.mesh import make_local_mesh
+
+    mesh = make_local_mesh(data=shards, model=1) if shards > 1 else None
+    sess = Session(mesh=mesh, mode="shard_map" if shards > 1 else "gspmd")
+    table = wisconsin.generate(rows, seed=11)
+    for name in ("data", "data_r"):
+        sess.create_dataset(name, table, dataverse="bench", closed=True,
+                            indexes=["onePercent", "unique1"],
+                            primary="unique2")
+    v = AFrameVariant("aframe-index", sess, "data")
+    t0 = time.perf_counter()
+    v.create()
+    creation = time.perf_counter() - t0
+    out = {}
+    for name, fn in EXPRESSIONS:
+        rng = np.random.default_rng(5)
+        ts = []
+        for _ in range(WARMUP + RUNS):
+            t0 = time.perf_counter()
+            fn(v, rng, rows)
+            ts.append(time.perf_counter() - t0)
+        out[name] = float(np.mean(ts[WARMUP:]))
+    return {"shards": shards, "rows": rows, "creation_s": creation,
+            "expr_s": out}
 
 
 def speedup(rows: int = 200_000, shard_counts=(1, 2, 4, 8)) -> list[dict]:

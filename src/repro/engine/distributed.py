@@ -31,10 +31,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, PartitionSpec as P
 
-try:
-    from jax import shard_map as _shard_map
-except ImportError:  # pragma: no cover
-    from jax.experimental.shard_map import shard_map as _shard_map
+from jax import shard_map as _shard_map
 
 from repro.engine import physical
 
@@ -47,12 +44,8 @@ def _smap(mesh, data_axes, fn, in_specs, out_specs):
     # check_vma=False: the replication checker cannot statically see that
     # all_gather + identical local computation yields replicated outputs
     # (merge-style operators below are deterministic post-gather).
-    try:
-        return _shard_map(fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                          check_vma=False)
-    except TypeError:  # older jax: check_rep
-        return _shard_map(fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                          check_rep=False)
+    return _shard_map(fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
+                      check_vma=False)
 
 
 # -- scalar aggregation -----------------------------------------------------------

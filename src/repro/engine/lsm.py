@@ -577,6 +577,10 @@ class BackgroundCompactor:
         happened, or happened atomically), so the retry rebuilds from
         intact components.
 
+    Any other exception is a bug: the worker gives up on that dataset,
+    counts it in ``lsm.compactor.errors_total``, and the next caller of
+    :meth:`wait_idle`, :meth:`wait_below` or :meth:`close` raises it.
+
     Writers needing backpressure (Feed's write stall) call
     :meth:`wait_below`, which sleeps on the worker's progress condition
     until the dataset's run count drops under the cap.
@@ -603,6 +607,8 @@ class BackgroundCompactor:
         self._inflight: dict[str, int] = {}
         self._threads: dict[str, threading.Thread] = {}
         self._stop = False
+        # the first unexpected worker failure; re-raised to the next waiter
+        self.error: Optional[BaseException] = None
 
     # -- control -----------------------------------------------------------
 
@@ -632,8 +638,10 @@ class BackgroundCompactor:
             while any(self._pending.values()) or any(self._inflight.values()):
                 remaining = deadline - time.perf_counter()
                 if remaining <= 0:
+                    self._raise_error()
                     return False
                 self._cv.wait(min(remaining, 0.05))
+        self._raise_error()
         return True
 
     def wait_below(self, dataverse: str, name: str, cap: int,
@@ -653,6 +661,7 @@ class BackgroundCompactor:
                 if remaining <= 0:
                     break
                 self._cv.wait(min(remaining, 0.05))
+        self._raise_error()
         return time.perf_counter() - t0
 
     def close(self) -> None:
@@ -662,6 +671,13 @@ class BackgroundCompactor:
             threads = list(self._threads.values())
         for t in threads:
             t.join(timeout=30.0)
+        self._raise_error()
+
+    def _raise_error(self) -> None:
+        """A worker that died of an unexpected exception stops compacting
+        its dataverse; whoever waits on the compactor next sees why."""
+        if self.error is not None:
+            raise RuntimeError("background compaction failed") from self.error
 
     def __enter__(self) -> "BackgroundCompactor":
         return self
@@ -721,8 +737,9 @@ class BackgroundCompactor:
             except StorageFault:
                 self._bump("faults")
                 failures += 1
-            except Exception:  # pragma: no cover - defensive: keep serving
+            except Exception as e:  # a bug, not a retryable condition
                 self._bump("errors")
+                self.error = self.error or e
                 return
             finally:
                 with self._cv:

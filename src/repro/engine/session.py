@@ -25,11 +25,7 @@ from repro.core.physical_planner import build_pruner, plan_physical
 from repro.engine.table import Table
 from repro.runtime import telemetry as tel
 
-try:
-    from jax import shard_map as _shard_map
-except ImportError:  # pragma: no cover
-    from jax.experimental.shard_map import shard_map as _shard_map
-
+from jax import shard_map as _shard_map
 from jax.sharding import PartitionSpec as PS
 
 
@@ -154,7 +150,7 @@ class Session:
 
         ``kernel_backend`` feeds the kernels/ops dispatch: 'pallas' forces
         the Pallas kernels (interpret mode off-TPU), 'xla' the jnp twins;
-        None picks pallas on TPU and the ops default elsewhere.
+        None takes ``kernels.ops.default_backend()`` (pallas on a TPU).
         ``kernel_interpret`` overrides the Pallas interpret auto-detection
         (None = compiled on TPU, interpret elsewhere).
 
@@ -197,9 +193,9 @@ class Session:
             raise ValueError(f"unknown kernel_backend {kernel_backend!r}: "
                              "expected None | xla | pallas")
         self.mode = mode
-        if kernel_backend is None and mode == "kernel" \
-                and jax.default_backend() == "tpu":
-            kernel_backend = "pallas"
+        if kernel_backend is None and mode == "kernel":
+            from repro.kernels import ops as kops
+            kernel_backend = kops.default_backend()
         self.kernel_backend = kernel_backend
         self.kernel_interpret = kernel_interpret
         self.data_axes = data_axes

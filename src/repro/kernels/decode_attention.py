@@ -14,12 +14,9 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-try:
-    from jax.experimental.pallas import tpu as pltpu
-    _VMEM = pltpu.VMEM
-except Exception:  # pragma: no cover
-    _VMEM = None
+from repro.kernels.filter_count import _resolve_interpret
 
 DEFAULT_BK = 1024
 NEG = -1e30
@@ -59,8 +56,9 @@ def _kernel(len_ref, q_ref, k_ref, v_ref, o_ref, acc_ref, m_ref, l_ref,
 
 @functools.partial(jax.jit, static_argnames=("bk", "interpret"))
 def flash_decode(q, k, v, lengths, *, bk: int = DEFAULT_BK,
-                 interpret: bool = True):
+                 interpret: bool | None = None):
     """q: (B,H,D); k,v: (B,KV,S,D); lengths: (B,) -> (B,H,D)."""
+    interpret = _resolve_interpret(interpret)
     B, H, D = q.shape
     KV, S = k.shape[1], k.shape[2]
     G = H // KV
@@ -83,9 +81,9 @@ def flash_decode(q, k, v, lengths, *, bk: int = DEFAULT_BK,
         out_specs=pl.BlockSpec((1, 1, G, D), lambda b, h, j: (b, h, 0, 0)),
         out_shape=jax.ShapeDtypeStruct((B, KV, G, D), q.dtype),
         scratch_shapes=[
-            _VMEM((G, D), jnp.float32) if _VMEM else None,
-            _VMEM((G, 1), jnp.float32) if _VMEM else None,
-            _VMEM((G, 1), jnp.float32) if _VMEM else None,
+            pltpu.VMEM((G, D), jnp.float32),
+            pltpu.VMEM((G, 1), jnp.float32),
+            pltpu.VMEM((G, 1), jnp.float32),
         ],
         interpret=interpret,
     )(lengths.astype(jnp.int32).reshape(B, 1), qg, k, v)

@@ -2,8 +2,8 @@
 
 One pass over k conjunct columns: each grid step loads a (k, BLOCK) tile
 into VMEM, evaluates the ANDed range predicates on the VPU, and accumulates
-a popcount into a (1,1) SMEM-style accumulator. Predicate *constants* arrive
-as a (k, 2) operand so randomized benchmark literals reuse the compiled
+a popcount into a (1,1) SMEM accumulator. Predicate *constants* arrive
+as a (k, 2) SMEM operand so randomized benchmark literals reuse the compiled
 kernel. This is the engine's answer to "SELECT COUNT(*) WHERE ..." — no
 intermediate mask column ever touches HBM.
 
@@ -31,13 +31,12 @@ BLOCK = 4096
 def _body(bounds_ref, nvalid_ref, cols_ref, out_ref, base):
     """Shared predicate/accumulate body; ``base`` is the first physical row
     index of this step's tile."""
-    cols = cols_ref[...]  # (k, BLOCK) int32
-    k, b = cols.shape
+    k, b = cols_ref.shape  # (k, BLOCK) int32 tile
     idx = base + jax.lax.broadcasted_iota(jnp.int32, (1, b), 1)
     ok = idx < nvalid_ref[0, 0]
-    lo = bounds_ref[:, 0][:, None]
-    hi = bounds_ref[:, 1][:, None]
-    ok = ok & jnp.all((cols >= lo) & (cols <= hi), axis=0, keepdims=True)
+    for j in range(k):  # k is static: one row per conjunct, scalar bounds
+        col = cols_ref[j:j + 1, :]
+        ok = ok & (col >= bounds_ref[j, 0]) & (col <= bounds_ref[j, 1])
     out_ref[0, 0] += jnp.sum(ok.astype(jnp.int32))
 
 
@@ -85,6 +84,11 @@ def _kernel_ids_arr(ids_ref, bounds_ref, nvalid_ref, cols_ref, out_ref):
               ids_ref[step] * cols_ref.shape[1])
 
 
+# Scalars (predicate bounds, the valid-row count, the count accumulator)
+# live in SMEM: the TPU stores scalars there and not in VMEM.
+_SMEM = pl.BlockSpec(memory_space=pltpu.SMEM)
+
+
 def _resolve_interpret(interpret):
     # None = auto: compiled Pallas on real TPUs, interpret mode elsewhere
     # (the kernels' semantics are validated everywhere, compiled where the
@@ -125,12 +129,12 @@ def filter_count(cols: jax.Array, bounds: jax.Array, n_valid,
             num_scalar_prefetch=1,
             grid=(int(ids.shape[0]),),
             in_specs=[
-                pl.BlockSpec((k, 2), lambda i, ids: (0, 0)),
-                pl.BlockSpec((1, 1), lambda i, ids: (0, 0)),
+                _SMEM,
+                _SMEM,
                 pl.BlockSpec((k, block),
                              lambda i, ids: (0, jnp.maximum(ids[i], 0))),
             ],
-            out_specs=pl.BlockSpec((1, 1), lambda i, ids: (0, 0)),
+            out_specs=_SMEM,
         )
         out = pl.pallas_call(
             _kernel_ids_arr,
@@ -144,11 +148,11 @@ def filter_count(cols: jax.Array, bounds: jax.Array, n_valid,
             _kernel,
             grid=(nb,),
             in_specs=[
-                pl.BlockSpec((k, 2), lambda i: (0, 0)),      # bounds: resident
-                pl.BlockSpec((1, 1), lambda i: (0, 0)),      # n_valid scalar
+                _SMEM,                                       # bounds: resident
+                _SMEM,                                       # n_valid scalar
                 pl.BlockSpec((k, block), lambda i: (0, i)),  # column tile
             ],
-            out_specs=pl.BlockSpec((1, 1), lambda i: (0, 0)),  # accumulator
+            out_specs=_SMEM,                                 # accumulator
             out_shape=jax.ShapeDtypeStruct((1, 1), jnp.int32),
             interpret=interpret,
         )(*args)
@@ -160,11 +164,11 @@ def filter_count(cols: jax.Array, bounds: jax.Array, n_valid,
         num_scalar_prefetch=1,
         grid=(len(block_ids),),
         in_specs=[
-            pl.BlockSpec((k, 2), lambda i, ids: (0, 0)),
-            pl.BlockSpec((1, 1), lambda i, ids: (0, 0)),
+            _SMEM,
+            _SMEM,
             pl.BlockSpec((k, block), lambda i, ids: (0, ids[i])),
         ],
-        out_specs=pl.BlockSpec((1, 1), lambda i, ids: (0, 0)),
+        out_specs=_SMEM,
     )
     out = pl.pallas_call(
         _kernel_ids,

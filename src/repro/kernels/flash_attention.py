@@ -16,12 +16,9 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-try:
-    from jax.experimental.pallas import tpu as pltpu
-    _VMEM = pltpu.VMEM
-except Exception:  # pragma: no cover
-    _VMEM = None
+from repro.kernels.filter_count import _resolve_interpret
 
 DEFAULT_BQ = 512
 DEFAULT_BK = 512
@@ -71,8 +68,9 @@ def _kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, acc_ref, m_ref, l_ref,
 
 @functools.partial(jax.jit, static_argnames=("causal", "bq", "bk", "interpret"))
 def flash_mha_fwd(q, k, v, *, causal: bool = True, bq: int = DEFAULT_BQ,
-                  bk: int = DEFAULT_BK, interpret: bool = True):
+                  bk: int = DEFAULT_BK, interpret: bool | None = None):
     """q: (B,H,Sq,D); k,v: (B,KV,Skv,D) -> (out (B,H,Sq,D), lse (B,H,Sq))."""
+    interpret = _resolve_interpret(interpret)
     B, H, Sq, D = q.shape
     KV, Skv = k.shape[1], k.shape[2]
     G = H // KV
@@ -85,9 +83,9 @@ def flash_mha_fwd(q, k, v, *, causal: bool = True, bq: int = DEFAULT_BQ,
     kernel = functools.partial(_kernel, scale=scale, causal=causal,
                                bq=bq, bk=bk, nkb=nkb)
     scratch = [
-        _VMEM((bq, D), jnp.float32) if _VMEM else None,
-        _VMEM((bq, 1), jnp.float32) if _VMEM else None,
-        _VMEM((bq, 1), jnp.float32) if _VMEM else None,
+        pltpu.VMEM((bq, D), jnp.float32),
+        pltpu.VMEM((bq, 1), jnp.float32),
+        pltpu.VMEM((bq, 1), jnp.float32),
     ]
     out, lse = pl.pallas_call(
         kernel,

@@ -15,6 +15,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro.kernels.filter_count import _SMEM, _resolve_interpret
+
 BLOCK = 1024
 
 
@@ -26,27 +28,32 @@ def _kernel(nl_ref, nr_ref, l_ref, r_ref, out_ref):
     def _init():
         out_ref[0, 0] = jnp.int32(0)
 
-    l = l_ref[0, :]  # (BL,) sorted ascending (global sort ⇒ block-sorted)
-    r = r_ref[0, :]  # (BR,)
-    bl, br = l.shape[0], r.shape[0]
-    lm = (i * bl + jax.lax.broadcasted_iota(jnp.int32, (bl,), 0)) < nl_ref[0, 0]
-    rm = (j * br + jax.lax.broadcasted_iota(jnp.int32, (br,), 0)) < nr_ref[0, 0]
+    bl, br = l_ref.shape[1], r_ref.shape[1]
     # zone check: block ranges must intersect (sorted ⇒ min/max at the ends)
-    l_lo, l_hi = l[0], l[bl - 1]
-    r_lo, r_hi = r[0], r[br - 1]
+    l_lo, l_hi = l_ref[0, 0], l_ref[0, bl - 1]
+    r_lo, r_hi = r_ref[0, 0], r_ref[0, br - 1]
     overlap = (l_lo <= r_hi) & (r_lo <= l_hi)
 
     @pl.when(overlap)
     def _count():
-        eq = (l[:, None] == r[None, :]) & lm[:, None] & rm[None, :]
+        # the left tile as a (BL, 1) column: a row cannot be reshaped across
+        # the lane/sublane boundary, so transpose a lane-broadcast copy
+        l_col = jnp.transpose(jnp.broadcast_to(l_ref[...], (128, bl)))[:, :1]
+        lm = (i * bl + jax.lax.broadcasted_iota(jnp.int32, (bl, 1), 0)) \
+            < nl_ref[0, 0]
+        rm = (j * br + jax.lax.broadcasted_iota(jnp.int32, (1, br), 1)) \
+            < nr_ref[0, 0]
+        eq = (l_col == r_ref[...]) & lm & rm
         out_ref[0, 0] += jnp.sum(eq.astype(jnp.int32))
 
 
 @functools.partial(jax.jit, static_argnames=("block", "interpret"))
 def merge_join_count(lkeys: jax.Array, rkeys: jax.Array, nl, nr,
-                     *, block: int = BLOCK, interpret: bool = True) -> jax.Array:
+                     *, block: int = BLOCK,
+                     interpret: bool | None = None) -> jax.Array:
     """lkeys/rkeys: sorted int32 (valid prefix of length nl/nr; +inf-style
     sentinel padding after). -> int32 join cardinality."""
+    interpret = _resolve_interpret(interpret)
     def padto(a):
         pad = (-a.shape[0]) % block
         if pad:
@@ -59,12 +66,12 @@ def merge_join_count(lkeys: jax.Array, rkeys: jax.Array, nl, nr,
         _kernel,
         grid=(l.shape[0] // block, r.shape[0] // block),
         in_specs=[
-            pl.BlockSpec((1, 1), lambda i, j: (0, 0)),
-            pl.BlockSpec((1, 1), lambda i, j: (0, 0)),
+            _SMEM,
+            _SMEM,
             pl.BlockSpec((1, block), lambda i, j: (0, i)),
             pl.BlockSpec((1, block), lambda i, j: (0, j)),
         ],
-        out_specs=pl.BlockSpec((1, 1), lambda i, j: (0, 0)),
+        out_specs=_SMEM,
         out_shape=jax.ShapeDtypeStruct((1, 1), jnp.int32),
         interpret=interpret,
     )(jnp.asarray(nl, jnp.int32).reshape(1, 1),

@@ -7,9 +7,9 @@ compile — e.g. the CPU-hosted dry-run); EITHER way the custom_vjp saves only
 no (Sq × Skv) probability tensor is ever stored. Swapping the models'
 attention onto this op is §Perf iteration 1 (memory-roofline win).
 
-Backend selection: ``backend="auto"`` uses Pallas-interpret on CPU (kernel
-semantics validated everywhere) and compiled Pallas on TPU; "xla" forces the
-jnp twin (what the dry-run lowers).
+Backend selection for the relational kernels: ``backend=None`` takes
+:func:`default_backend` — compiled Pallas on a TPU, the XLA twins elsewhere;
+"pallas" forces the kernels (interpret mode off the TPU), "xla" the twins.
 """
 from __future__ import annotations
 
@@ -22,14 +22,13 @@ import numpy as np
 
 from repro.kernels import ref
 from repro.kernels.decode_attention import flash_decode as _flash_decode_pallas
+from repro.kernels.filter_count import _resolve_interpret
 from repro.kernels.filter_count import filter_count as _filter_count
 from repro.kernels.flash_attention import flash_mha_fwd as _flash_fwd_pallas
 from repro.kernels.merge_join import merge_join_count as _merge_join
 from repro.kernels.segment_agg import segment_agg as _segment_agg
 from repro.kernels.topk_mask import topk_merge as _topk_merge
 from repro.runtime import telemetry as tel
-
-_DEFAULT_BACKEND = "xla"
 
 # Trace-time dispatch counters: the kernel execution mode's tests assert the
 # relational kernels are actually on the lowered path (one tick per trace,
@@ -43,7 +42,8 @@ def reset_dispatch_counts() -> None:
 
 def _tick(name: str, grid: Optional[int] = None,
           blocks_total: Optional[int] = None,
-          backend: Optional[str] = None) -> None:
+          backend: Optional[str] = None,
+          interpret: Optional[bool] = None) -> None:
     """One tick per trace. Mirrors into the telemetry registry with the
     launch shape: which backend (pallas/xla), interpret vs compiled, and —
     for the block-skipping kernels — grid size vs the component's physical
@@ -52,7 +52,7 @@ def _tick(name: str, grid: Optional[int] = None,
     pallas = _use_pallas(backend)
     tel.inc("kernel.launches_total", kernel=name,
             backend="pallas" if pallas else "xla",
-            interpret=str(pallas and _interpret()).lower())
+            interpret=str(pallas and _resolve_interpret(interpret)).lower())
     if grid is not None:
         tel.inc("kernel.grid_blocks_total", grid, kernel=name)
         if blocks_total is not None:
@@ -61,20 +61,14 @@ def _tick(name: str, grid: Optional[int] = None,
                     kernel=name)
 
 
-def set_default_backend(name: str) -> None:
-    global _DEFAULT_BACKEND
-    assert name in ("xla", "pallas")
-    _DEFAULT_BACKEND = name
+def default_backend() -> str:
+    """The one rule for an unspecified kernel backend: the Pallas kernels
+    wherever they compile (a TPU), their XLA twins elsewhere."""
+    return "pallas" if jax.default_backend() == "tpu" else "xla"
 
 
 def _use_pallas(backend: Optional[str]) -> bool:
-    b = backend or _DEFAULT_BACKEND
-    return b == "pallas"
-
-
-def _interpret() -> bool:
-    from repro.kernels.filter_count import _resolve_interpret
-    return _resolve_interpret(None)
+    return (backend or default_backend()) == "pallas"
 
 
 # -- relational kernels ------------------------------------------------------------
@@ -134,23 +128,21 @@ def filter_count(cols, bounds, n_valid, backend: Optional[str] = None,
         # grid length is the padded list; true scanned/skipped telemetry is
         # accounted host-side by the distributed wrapper, not here.
         _tick("filter_count", grid=int(block_ids_arr.shape[0]),
-              backend=backend)
+              backend=backend, interpret=interpret)
         if _use_pallas(backend):
             return _filter_count(cols, bounds, n_valid,
                                  block_ids_arr=block_ids_arr,
-                                 interpret=_interpret() if interpret is None
-                                 else interpret)
+                                 interpret=interpret)
         return ref.filter_count(cols, bounds, n_valid,
                                 block_ids_arr=block_ids_arr, block=_FC_BLOCK)
     ids = _expand_block_ids(block_ids, ZONE_BLOCK_ROWS, _FC_BLOCK,
                             cols.shape[1])
     nb = -(-cols.shape[1] // _FC_BLOCK)
     _tick("filter_count", grid=len(ids) if ids is not None else nb,
-          blocks_total=nb, backend=backend)
+          blocks_total=nb, backend=backend, interpret=interpret)
     if _use_pallas(backend):
         return _filter_count(cols, bounds, n_valid, block_ids=ids,
-                             interpret=_interpret() if interpret is None
-                             else interpret)
+                             interpret=interpret)
     return ref.filter_count(cols, bounds, n_valid, block_ids=ids,
                             block=_FC_BLOCK)
 
@@ -163,24 +155,21 @@ def segment_agg(values, gids, num_groups, n_valid, op: str = "sum",
     from repro.kernels.segment_agg import BLOCK as _SA_BLOCK
     if block_ids_arr is not None:
         _tick("segment_agg", grid=int(block_ids_arr.shape[0]),
-              backend=backend)
+              backend=backend, interpret=interpret)
         if _use_pallas(backend):
             return _segment_agg(values, gids, num_groups, n_valid, op=op,
                                 block_ids_arr=block_ids_arr,
-                                interpret=_interpret() if interpret is None
-                                else interpret)
+                                interpret=interpret)
         return ref.segment_agg(values, gids, num_groups, n_valid, op,
                                block_ids_arr=block_ids_arr, block=_SA_BLOCK)
     ids = _expand_block_ids(block_ids, ZONE_BLOCK_ROWS, _SA_BLOCK,
                             values.shape[0])
     nb = -(-values.shape[0] // _SA_BLOCK)
     _tick("segment_agg", grid=len(ids) if ids is not None else nb,
-          blocks_total=nb, backend=backend)
+          blocks_total=nb, backend=backend, interpret=interpret)
     if _use_pallas(backend):
         return _segment_agg(values, gids, num_groups, n_valid, op=op,
-                            block_ids=ids,
-                            interpret=_interpret() if interpret is None
-                            else interpret)
+                            block_ids=ids, interpret=interpret)
     return ref.segment_agg(values, gids, num_groups, n_valid, op,
                            block_ids=ids, block=_SA_BLOCK)
 
@@ -203,7 +192,7 @@ def merge_join_count(lkeys, rkeys, nl, nr, backend: Optional[str] = None):
     compare matrix is a test oracle, not an execution path."""
     _tick("merge_join_count", backend=backend)
     if _use_pallas(backend):
-        return _merge_join(lkeys, rkeys, nl, nr, interpret=_interpret())
+        return _merge_join(lkeys, rkeys, nl, nr)
     lo = jnp.searchsorted(rkeys, lkeys, side="left")
     hi = jnp.minimum(jnp.searchsorted(rkeys, lkeys, side="right"), nr)
     lm = jnp.arange(lkeys.shape[0]) < nl
@@ -215,7 +204,7 @@ def topk(scores, mask, n_valid, k, backend: Optional[str] = None):
     identical tie-breaking (lowest index first) on both backends."""
     _tick("topk", backend=backend)
     if _use_pallas(backend):
-        return _topk_merge(scores, mask, n_valid, k, interpret=_interpret())
+        return _topk_merge(scores, mask, n_valid, k)
     live = mask & (jnp.arange(scores.shape[0]) < n_valid)
     s = jnp.where(live, scores.astype(jnp.float32), -jnp.inf)
     vals, idx = jax.lax.top_k(s, k)
@@ -284,8 +273,7 @@ def flash_attention(q, k, v, causal: bool = True, bq: int = 512,
 
 def _flash_fwd_dispatch(q, k, v, causal, bq, backend):
     if backend == "pallas":
-        return _flash_fwd_pallas(q, k, v, causal=causal, bq=min(bq, q.shape[2]),
-                                 interpret=_interpret())
+        return _flash_fwd_pallas(q, k, v, causal=causal, bq=min(bq, q.shape[2]))
     return _xla_flash_fwd(q, k, v, causal, bq)
 
 
@@ -364,5 +352,5 @@ flash_attention.defvjp(_flash_fwd_rule, _flash_bwd_rule)
 def flash_decode(q, k, v, lengths, backend: Optional[str] = None):
     """Single-token decode attention. q: (B,H,D); k,v: (B,KV,S,D)."""
     if _use_pallas(backend):
-        return _flash_decode_pallas(q, k, v, lengths, interpret=_interpret())
+        return _flash_decode_pallas(q, k, v, lengths)
     return ref.decode_attention(q, k, v, lengths)

@@ -9,9 +9,11 @@ ids) make G small, so the one-hot GEMM beats scatter-adds on TPU, which has
 no efficient random-access memory path.
 
 ``op`` selects the reduction: "sum" (the MXU matmul above) or "max"/"min"
-(VPU select-and-reduce over the same one-hot tile — not sum-shaped, so no
-matmul, but the same blocked revisit pattern keeps the (G, C) accumulator in
-VMEM). max/min feed group extremes for the kernel execution mode and the
+(VPU select-and-reduce over the same one-hot tile, one value column at a
+time — not sum-shaped, so no matmul, but the same blocked revisit pattern
+keeps the (G, C) accumulator in VMEM). The max/min launch takes its values
+column-major, (C, n), so each column is a lane-dense (1, BLOCK) row.
+max/min feed group extremes for the kernel execution mode and the
 incrementally-maintained views of the streaming ingestion subsystem.
 
 ``block_ids`` drives the grid through only the listed blocks (zone-map
@@ -35,24 +37,27 @@ _INIT = {"sum": 0.0, "max": -jnp.inf, "min": jnp.inf}
 
 
 def _body(op, nvalid_ref, gid_ref, val_ref, out_ref, base):
-    gids = gid_ref[0, :]  # (BLOCK,)
-    vals = val_ref[...]   # (BLOCK, C)
-    b = gids.shape[0]
+    gids = gid_ref[...]  # (1, BLOCK)
+    b = gids.shape[1]
     G = out_ref.shape[0]
-    live = (base + jax.lax.broadcasted_iota(jnp.int32, (b,), 0)) < nvalid_ref[0, 0]
+    live = (base + jax.lax.broadcasted_iota(jnp.int32, (1, b), 1)) < nvalid_ref[0, 0]
     live = live & (gids >= 0) & (gids < G)
-    onehot = (jax.lax.broadcasted_iota(jnp.int32, (G, b), 0) == gids[None, :])
+    onehot = jax.lax.broadcasted_iota(jnp.int32, (G, b), 0) == gids
     if op == "sum":
-        oh = onehot.astype(jnp.float32) * live[None, :].astype(jnp.float32)
+        vals = val_ref[...]  # (BLOCK, C)
+        oh = onehot.astype(jnp.float32) * live.astype(jnp.float32)
         out_ref[...] += jax.lax.dot(oh, vals.astype(jnp.float32),
                                     preferred_element_type=jnp.float32)
-    else:
-        sel = (onehot & live[None, :])[:, :, None]  # (G, b, 1)
-        cand = jnp.where(sel, vals[None, :, :].astype(jnp.float32), _INIT[op])
-        if op == "max":
-            out_ref[...] = jnp.maximum(out_ref[...], jnp.max(cand, axis=1))
-        else:
-            out_ref[...] = jnp.minimum(out_ref[...], jnp.min(cand, axis=1))
+        return
+    # max/min: value tiles arrive as (C, BLOCK) rows, one (G, BLOCK)
+    # select-and-reduce per column
+    sel = onehot & live
+    red = jnp.max if op == "max" else jnp.min
+    comb = jnp.maximum if op == "max" else jnp.minimum
+    for c in range(val_ref.shape[0]):
+        cand = jnp.where(sel, val_ref[c:c + 1, :].astype(jnp.float32), _INIT[op])
+        out_ref[:, c:c + 1] = comb(out_ref[:, c:c + 1],
+                                   red(cand, axis=1, keepdims=True))
 
 
 def _kernel(op, nvalid_ref, gid_ref, val_ref, out_ref):
@@ -116,7 +121,7 @@ def segment_agg(values: jax.Array, gids: jax.Array, num_groups: int, n_valid,
     (m,) int32 per-shard alternative, ``-1``-padded at the end (mutually
     exclusive with ``block_ids``)."""
     assert op in _INIT, op
-    from repro.kernels.filter_count import _resolve_interpret
+    from repro.kernels.filter_count import _SMEM, _resolve_interpret
     interpret = _resolve_interpret(interpret)
     n, c = values.shape
     pad = (-n) % block
@@ -124,6 +129,11 @@ def segment_agg(values: jax.Array, gids: jax.Array, num_groups: int, n_valid,
         values = jnp.pad(values, ((0, pad), (0, 0)))
         gids = jnp.pad(gids, (0, pad))
     nb = values.shape[0] // block
+    if op == "sum":
+        vspec = lambda at: pl.BlockSpec((block, c), lambda i, *ids: (at(i, *ids), 0))
+    else:
+        values = values.T
+        vspec = lambda at: pl.BlockSpec((c, block), lambda i, *ids: (0, at(i, *ids)))
     args = [jnp.asarray(n_valid, jnp.int32).reshape(1, 1),
             gids.astype(jnp.int32).reshape(1, -1), values]
     if block_ids_arr is not None:
@@ -133,11 +143,10 @@ def segment_agg(values: jax.Array, gids: jax.Array, num_groups: int, n_valid,
             num_scalar_prefetch=1,
             grid=(int(ids.shape[0]),),
             in_specs=[
-                pl.BlockSpec((1, 1), lambda i, ids: (0, 0)),
+                _SMEM,
                 pl.BlockSpec((1, block),
                              lambda i, ids: (0, jnp.maximum(ids[i], 0))),
-                pl.BlockSpec((block, c),
-                             lambda i, ids: (jnp.maximum(ids[i], 0), 0)),
+                vspec(lambda i, ids: jnp.maximum(ids[i], 0)),
             ],
             out_specs=pl.BlockSpec((num_groups, c), lambda i, ids: (0, 0)),
         )
@@ -152,9 +161,9 @@ def segment_agg(values: jax.Array, gids: jax.Array, num_groups: int, n_valid,
             functools.partial(_kernel, op),
             grid=(nb,),
             in_specs=[
-                pl.BlockSpec((1, 1), lambda i: (0, 0)),
+                _SMEM,
                 pl.BlockSpec((1, block), lambda i: (0, i)),
-                pl.BlockSpec((block, c), lambda i: (i, 0)),
+                vspec(lambda i: i),
             ],
             out_specs=pl.BlockSpec((num_groups, c), lambda i: (0, 0)),
             out_shape=jax.ShapeDtypeStruct((num_groups, c), jnp.float32),
@@ -167,9 +176,9 @@ def segment_agg(values: jax.Array, gids: jax.Array, num_groups: int, n_valid,
         num_scalar_prefetch=1,
         grid=(len(block_ids),),
         in_specs=[
-            pl.BlockSpec((1, 1), lambda i, ids: (0, 0)),
+            _SMEM,
             pl.BlockSpec((1, block), lambda i, ids: (0, ids[i])),
-            pl.BlockSpec((block, c), lambda i, ids: (ids[i], 0)),
+            vspec(lambda i, ids: ids[i]),
         ],
         out_specs=pl.BlockSpec((num_groups, c), lambda i, ids: (0, 0)),
     )

@@ -14,31 +14,43 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro.kernels.filter_count import _SMEM, _resolve_interpret
+
 BLOCK = 4096
 NEG = float("-inf")
 
 
 def _kernel(nvalid_ref, scores_ref, mask_ref, vals_ref, idx_ref):
     step = pl.program_id(0)
-    s = scores_ref[0, :].astype(jnp.float32)
-    m = mask_ref[0, :]
-    b = s.shape[0]
+    s = scores_ref[...]  # (1, BLOCK) f32
+    b = s.shape[1]
     base = step * b
-    live = ((base + jax.lax.broadcasted_iota(jnp.int32, (b,), 0)) < nvalid_ref[0, 0])
-    s = jnp.where(m & live, s, NEG)
-    k = vals_ref.shape[1]
+    lane = jax.lax.broadcasted_iota(jnp.int32, (1, b), 1)
+    live = (base + lane) < nvalid_ref[0, 0]
+    s = jnp.where((mask_ref[...] != 0) & live, s, NEG)
+    k = vals_ref.shape[-1]
+    slot = jax.lax.broadcasted_iota(jnp.int32, (1, k), 1)
+    vals = jnp.zeros((1, k), jnp.float32)
+    idx = jnp.zeros((1, k), jnp.int32)
     for kk in range(k):  # k is static & small
-        v = jnp.max(s)
-        a = jnp.argmax(s).astype(jnp.int32)
-        vals_ref[0, kk] = v
-        idx_ref[0, kk] = base + a
-        s = jnp.where(jax.lax.broadcasted_iota(jnp.int32, (b,), 0) == a, NEG, s)
+        v = jnp.max(s, axis=1, keepdims=True)
+        a = jnp.argmax(s, axis=1, keepdims=True).astype(jnp.int32)
+        vals = jnp.where(slot == kk, v, vals)
+        idx = jnp.where(slot == kk, base + a, idx)
+        s = jnp.where(lane == a, NEG, s)
+    vals_ref[...] = vals
+    idx_ref[...] = idx
 
 
 @functools.partial(jax.jit, static_argnames=("k", "block", "interpret"))
 def block_topk(scores: jax.Array, mask: jax.Array, n_valid, k: int,
-               *, block: int = BLOCK, interpret: bool = True):
-    """scores (n,), mask (n,) -> (values (nb, k), indices (nb, k))."""
+               *, block: int = BLOCK, interpret: bool | None = None):
+    """scores (n,), mask (n,) -> (values (nb, k), indices (nb, k)).
+
+    The mask travels as int32 (the TPU has no bool memory tiles), and each
+    block's k results land in a (1, k) row of an (nb, 1, k) output, whose
+    trailing block dims equal the array's."""
+    interpret = _resolve_interpret(interpret)
     n = scores.shape[0]
     pad = (-n) % block
     if pad:
@@ -49,22 +61,23 @@ def block_topk(scores: jax.Array, mask: jax.Array, n_valid, k: int,
         _kernel,
         grid=(nb,),
         in_specs=[
-            pl.BlockSpec((1, 1), lambda i: (0, 0)),
+            _SMEM,
             pl.BlockSpec((1, block), lambda i: (0, i)),
             pl.BlockSpec((1, block), lambda i: (0, i)),
         ],
-        out_specs=[pl.BlockSpec((1, k), lambda i: (i, 0)),
-                   pl.BlockSpec((1, k), lambda i: (i, 0))],
-        out_shape=[jax.ShapeDtypeStruct((nb, k), jnp.float32),
-                   jax.ShapeDtypeStruct((nb, k), jnp.int32)],
+        out_specs=[pl.BlockSpec((None, 1, k), lambda i: (i, 0, 0)),
+                   pl.BlockSpec((None, 1, k), lambda i: (i, 0, 0))],
+        out_shape=[jax.ShapeDtypeStruct((nb, 1, k), jnp.float32),
+                   jax.ShapeDtypeStruct((nb, 1, k), jnp.int32)],
         interpret=interpret,
     )(jnp.asarray(n_valid, jnp.int32).reshape(1, 1),
-      scores.astype(jnp.float32).reshape(1, -1), mask.reshape(1, -1))
-    return vals, idx
+      scores.astype(jnp.float32).reshape(1, -1),
+      mask.astype(jnp.int32).reshape(1, -1))
+    return vals.reshape(nb, k), idx.reshape(nb, k)
 
 
 def topk_merge(scores, mask, n_valid, k: int, *, block: int = BLOCK,
-               interpret: bool = True):
+               interpret: bool | None = None):
     """Full top-k: block_topk + one small merge (the k×nb candidate set)."""
     vals, idx = block_topk(scores, mask, n_valid, k, block=block,
                            interpret=interpret)

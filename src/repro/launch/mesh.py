@@ -18,7 +18,14 @@ import dataclasses
 import math
 
 import jax
-from jax.sharding import Mesh
+from jax.sharding import AxisType, Mesh
+
+
+def _auto_mesh(shape, axes, devices) -> Mesh:
+    # Auto axes: the engine and the models place data with NamedSharding and
+    # shard_map themselves; jax.make_mesh would otherwise build Explicit axes.
+    return jax.make_mesh(shape, axes, devices=devices,
+                         axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False) -> Mesh:
@@ -32,16 +39,17 @@ def make_production_mesh(*, multi_pod: bool = False) -> Mesh:
             "the dry-run launcher must set XLA_FLAGS=--xla_force_host_platform_"
             "device_count=512 before importing jax"
         )
-    return jax.make_mesh(shape, axes, devices=devices[:ndev])
+    return _auto_mesh(shape, axes, devices[:ndev])
 
 
 def make_local_mesh(data: int = 1, model: int = 1) -> Mesh:
-    """A small mesh over whatever devices exist (tests, CPU benches)."""
+    """A mesh over the first ``data * model`` local devices (one chip's
+    sessions, a four-chip host, or forced CPU host devices in tests)."""
     ndev = data * model
     devices = jax.devices()
     if len(devices) < ndev:
         raise RuntimeError(f"need {ndev} devices, have {len(devices)}")
-    return jax.make_mesh((data, model), ("data", "model"), devices=devices[:ndev])
+    return _auto_mesh((data, model), ("data", "model"), devices[:ndev])
 
 
 @dataclasses.dataclass(frozen=True)

@@ -199,10 +199,7 @@ def _decode_attention_smap(q, k_new, v_new, cache_k_l, cache_v_l, pos, cfg, ctx)
     so the cache write is a 1-token in-place DUS on the owning rank (GSPMD's
     sharded-dim DUS lowers to a full-buffer select — §Perf iteration C4) and
     the softmax reduces over "model" with two tiny psums."""
-    try:
-        from jax import shard_map as _sm
-    except ImportError:  # pragma: no cover
-        from jax.experimental.shard_map import shard_map as _sm
+    from jax import shard_map as _sm
     from jax.sharding import PartitionSpec as P
 
     mesh, axes = ctx.mesh, ctx.axes
@@ -249,10 +246,7 @@ def _decode_attention_smap(q, k_new, v_new, cache_k_l, cache_v_l, pos, cfg, ctx)
                   P(dp, M, None, None), P()),
         out_specs=(P(dp, None, None, None, None), P(dp, M, None, None),
                    P(dp, M, None, None)))
-    try:
-        smapped = _sm(local, **kwargs, check_vma=False)
-    except TypeError:  # older jax: check_rep
-        smapped = _sm(local, **kwargs, check_rep=False)
+    smapped = _sm(local, **kwargs, check_vma=False)
     return smapped(q, k_new, v_new, cache_k_l, cache_v_l, pos)
 
 

@@ -24,11 +24,7 @@ from repro.models.config import ArchConfig, MoESpec
 from repro.models.layers import he_init, mlp
 from repro.models.sharding import current_ctx
 
-try:  # jax >= 0.6 exposes shard_map at top level
-    from jax import shard_map as _shard_map
-except ImportError:  # pragma: no cover
-    from jax.experimental.shard_map import shard_map as _shard_map
-
+from jax import shard_map as _shard_map
 from jax.sharding import PartitionSpec as P
 
 

@@ -522,9 +522,7 @@ def test_sharded_block_skip_equivalence_property():
     """The acceptance property on an 8-shard mesh: sharded-with-block-skip ≡
     unsharded ≡ skip-disabled in all three modes over a mutated,
     uncompacted dataset (hypothesis sweeps the predicate range), and the
-    per-shard kernel grids provably skip blocks. Hypothesis drives the
-    sweep when installed; otherwise a deterministic grid covers the same
-    boundary cases (block edges, shard edges, run spans, empty ranges)."""
+    per-shard kernel grids provably skip blocks."""
     from test_distributed import run_script
 
     run_script(_SHARDED_PRELUDE + """
@@ -549,22 +547,14 @@ def check_one(qlo, qw):
         finally:
             sess.enable_block_skip = True
 
-try:
-    from hypothesis import given, settings, strategies as st
-except ImportError:
-    # deterministic boundary grid: shard edges (2500-row partitions land at
-    # 512-multiples nearby), zone-block edges, the appended run's span, the
-    # tombstoned block, and off-the-end empties
-    for qlo, qw in [(0, 0), (0, 6), (4, 1), (7, 3), (15, 4), (16, 0),
-                    (16, 6), (19, 2), (38, 5), (40, 3), (43, 6)]:
-        check_one(qlo, qw)
-else:
-    @settings(deadline=None, max_examples=8, database=None)
-    @given(st.integers(0, 43), st.integers(0, 6))
-    def check(qlo, qw):
-        check_one(qlo, qw)
+from hypothesis import given, settings, strategies as st
 
-    check()
+@settings(deadline=None, max_examples=8, database=None)
+@given(st.integers(0, 43), st.integers(0, 6))
+def check(qlo, qw):
+    check_one(qlo, qw)
+
+check()
 
 # a 1-block-selective predicate on the 8-shard mesh provably skips: the base
 # lays out 8 per-shard blocks and only the owning shard's block is scanned

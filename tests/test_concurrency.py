@@ -143,6 +143,35 @@ def test_no_reader_blocks_on_running_compaction(monkeypatch):
     assert _observe(df) == _expected(oracle)
 
 
+def test_compactor_bug_reaches_the_waiter(monkeypatch):
+    """An exception that is neither a lost CAS nor a storage fault is a bug:
+    the compactor counts it, leaves the manifest untouched and raises it to
+    the next waiter instead of reporting an idle, healthy worker."""
+    sess, oracle = _setup("gspmd")
+    feed = Feed(sess, "Live", "d", flush_rows=8, policy=DEFERRED)
+    feed.push(_rows(np.arange(48, 56)))
+    oracle.update({k: (1 + k * 7 % 100, k % 5) for k in range(48, 56)})
+
+    def broken(*a, **kw):
+        raise ValueError("merge bug")
+
+    real = lsm._visible_columns
+    monkeypatch.setattr(lsm, "_visible_columns", broken)
+    errors0 = tel.counter_value("lsm.compactor.errors_total") or 0
+    bc = lsm.BackgroundCompactor(sess, policy=lsm.CompactionPolicy(size_ratio=0.0))
+    bc.notify("d", "Live")
+    with pytest.raises(RuntimeError, match="background compaction failed") as ei:
+        bc.wait_idle(30.0)
+    assert isinstance(ei.value.__cause__, ValueError)
+    assert bc.stats["errors"] == 1
+    assert tel.counter_value("lsm.compactor.errors_total") == errors0 + 1
+    with pytest.raises(RuntimeError):
+        bc.close()
+    monkeypatch.setattr(lsm, "_visible_columns", real)
+    assert len(sess.catalog.get("d", "Live").runs) == 1
+    assert _observe(AFrame("d", "Live", session=sess)) == _expected(oracle)
+
+
 def test_write_stall_backpressures_writer_not_readers():
     """Past the hard run cap the WRITER blocks (bounded by the stall
     timeout); a concurrent reader still answers correctly."""

@@ -226,10 +226,7 @@ import numpy as np, jax, jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 from repro.launch.mesh import make_local_mesh
 from repro.runtime.compress import compressed_psum, init_error_state
-try:
-    from jax import shard_map
-except ImportError:
-    from jax.experimental.shard_map import shard_map
+from jax import shard_map
 
 mesh = make_local_mesh(8, 1)
 g_local = np.random.default_rng(0).normal(size=(8, 128)).astype(np.float32)
