@@ -26,6 +26,7 @@ lowerings.
 from __future__ import annotations
 
 import dataclasses
+import hashlib
 import time
 from typing import Any, Callable, Optional
 
@@ -37,6 +38,7 @@ from repro.core import physical as PH
 from repro.core.catalog import INTERNAL_COLUMNS, Catalog
 from repro.core.expr import collect_params, param_values
 from repro.engine import physical
+from repro.kernels import ops
 from repro.runtime import telemetry as tel
 
 
@@ -73,7 +75,6 @@ class LoweringStrategy:
     def kernel_group_agg(self, gid, values, num_groups, n, op,
                          block_ids: Optional[tuple] = None,
                          shard_blocks=None):
-        from repro.kernels import ops
         assert shard_blocks is None, \
             "per-shard grids need the shard_map strategy"
         return ops.segment_agg(values, gid, num_groups, n, op=op,
@@ -84,7 +85,6 @@ class LoweringStrategy:
     def kernel_filter_count(self, mat, bounds,
                             block_ids: Optional[tuple] = None,
                             shard_blocks=None):
-        from repro.kernels import ops
         assert shard_blocks is None, \
             "per-shard grids need the shard_map strategy"
         return ops.filter_count(mat, bounds, mat.shape[1],
@@ -114,7 +114,6 @@ class LoweringStrategy:
         return physical.join_count(lkey, lmask, rkey, rmask)
 
     def kernel_join_count(self, lkey, lmask, rkey, rmask, presorted):
-        from repro.kernels import ops
         ls = ops.sort_join_keys(lkey, lmask)
         rs = ops.sort_join_keys(rkey, rmask, presorted=presorted)
         nl = jnp.sum(lmask, dtype=jnp.int32)
@@ -245,6 +244,9 @@ class CompiledQuery:
     #                             plan subtracts with (may include runs whose
     #                             MATTER was zone-pruned — their tombstones
     #                             still annihilate into older components)
+    launches: dict = dataclasses.field(default_factory=dict)
+    #                             kernel.* series id -> count per execution,
+    #                             recorded when ``fn`` traces
 
     def gather_tables(self, catalog: Catalog) -> dict:
         tables = {}
@@ -268,7 +270,23 @@ class CompiledQuery:
         (same fingerprint ⇒ same slot order)."""
         if params is None:
             params = param_values(lits if lits is not None else self.lits)
-        return self.fn(self.gather_tables(catalog), params)
+        return self.call(self.gather_tables(catalog), params)
+
+    def call(self, tables: dict, params):
+        """Dispatch ``fn`` (the result may still be computing) and count
+        the kernel launches its trace recorded, once per execution."""
+        out = self.fn(tables, params)
+        if self.launches:
+            tel.registry().inc_series(self.launches)
+        return out
+
+
+def program_name(kind: str, fingerprint: str) -> str:
+    """Name of a query's jitted program, which its XLA module takes
+    (``jit_<name>``): the terminal kind and 8 hex digits of a hash of the
+    physical fingerprint, so a device trace names the query shape."""
+    digest = hashlib.sha1(fingerprint.encode()).hexdigest()[:8]
+    return f"aframe_{kind}_{digest}"
 
 
 def compile_physical(logical, phys: PH.PhysOp, ctx: ExecContext) -> CompiledQuery:
@@ -276,10 +294,16 @@ def compile_physical(logical, phys: PH.PhysOp, ctx: ExecContext) -> CompiledQuer
     leaf_keys = PH.scan_leaves(phys)
     lits = collect_params(PH.all_exprs(phys))
     kind, build = _lower_terminal(phys, ctx)
-    jitted = jax.jit(build)
-    return CompiledQuery(logical, phys, phys.fingerprint(), kind, jitted,
+    fp = phys.fingerprint()
+    launches: dict = {}
+
+    def program(tables, params):
+        with ops.recording_launches(launches):
+            return build(tables, params)
+    program.__name__ = program.__qualname__ = program_name(kind, fp)
+    return CompiledQuery(logical, phys, fp, kind, jax.jit(program),
                          leaf_keys, lits, raw_fn=build,
-                         anti_keys=PH.anti_leaves(phys))
+                         anti_keys=PH.anti_leaves(phys), launches=launches)
 
 
 def compile_plan(opt_plan, ctx: ExecContext, *, enable_index: bool = True,
@@ -652,7 +676,6 @@ def _lower_kernel_segment_agg(node: PH.KernelSegmentAgg, ctx: ExecContext,
         ids, zb = blk[0], blk[1]
         nsh, bp, rps = (blk[2:5] if len(blk) >= 5 else (1, 0, 0))
         if nsh > 1:
-            from repro.kernels import ops
             from repro.kernels.segment_agg import BLOCK as _SA_BLOCK
             resolved.append((None, ops.shard_block_arrays(
                 ids, zb, _SA_BLOCK, nsh, bp, rps)))
@@ -820,7 +843,6 @@ def _lower_kernel_range_count(node: PH.KernelRangeCount, ctx: ExecContext) -> Ca
     if block_ids is not None and nsh > 1:
         # multi-shard layout: expand the flat zone-block survivors into the
         # per-shard kernel-block matrix each shard scalar-prefetches.
-        from repro.kernels import ops
         from repro.kernels.filter_count import BLOCK as _FC_BLOCK
         shard_blocks = ops.shard_block_arrays(block_ids, node.zone_block,
                                               _FC_BLOCK, nsh, bp, rps)

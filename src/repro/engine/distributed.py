@@ -274,7 +274,6 @@ def dist_kernel_filter_count(mesh: Mesh, data_axes, cols_mat: jax.Array,
     pad steps are gated no-ops."""
     from repro.kernels import ops
     from repro.kernels.filter_count import BLOCK as _FC_BLOCK
-    from repro.runtime import telemetry as tel
 
     dp = _dp(data_axes)
     if block_ids is not None:
@@ -290,9 +289,10 @@ def dist_kernel_filter_count(mesh: Mesh, data_axes, cols_mat: jax.Array,
         # visible — the per-shard grid length over-counts by the padding.
         nb_local = -(-(cols_mat.shape[1] // nsh) // _FC_BLOCK)
         scanned = int((sb >= 0).sum())
-        tel.inc("kernel.blocks_scanned_total", scanned, kernel="filter_count")
-        tel.inc("kernel.blocks_skipped_total", nsh * nb_local - scanned,
-                kernel="filter_count")
+        ops.count_kernel("kernel.blocks_scanned_total", scanned,
+                         kernel="filter_count")
+        ops.count_kernel("kernel.blocks_skipped_total",
+                         nsh * nb_local - scanned, kernel="filter_count")
 
         def local_arr(cm, b, ids):
             c = ops.filter_count(cm, b, cm.shape[1], backend=backend,
@@ -324,7 +324,6 @@ def dist_kernel_group_agg(mesh: Mesh, data_axes, gids: jax.Array,
     ids are in segment_agg's OWN kernel-block units)."""
     from repro.kernels import ops
     from repro.kernels.segment_agg import BLOCK as _SA_BLOCK
-    from repro.runtime import telemetry as tel
 
     dp = _dp(data_axes)
     merge = {"sum": jax.lax.psum, "max": jax.lax.pmax, "min": jax.lax.pmin}[op]
@@ -339,9 +338,10 @@ def dist_kernel_group_agg(mesh: Mesh, data_axes, gids: jax.Array,
         assert sb.shape[0] == nsh, (sb.shape, nsh)
         nb_local = -(-(gids.shape[0] // nsh) // _SA_BLOCK)
         scanned = int((sb >= 0).sum())
-        tel.inc("kernel.blocks_scanned_total", scanned, kernel="segment_agg")
-        tel.inc("kernel.blocks_skipped_total", nsh * nb_local - scanned,
-                kernel="segment_agg")
+        ops.count_kernel("kernel.blocks_scanned_total", scanned,
+                         kernel="segment_agg")
+        ops.count_kernel("kernel.blocks_skipped_total",
+                         nsh * nb_local - scanned, kernel="segment_agg")
 
         def local_arr(g, v, ids):
             out = ops.segment_agg(v, g, num_groups, v.shape[0], op=op,

@@ -786,31 +786,44 @@ class Session:
         from repro.core.expr import ordered_lits
         from repro.core.physical import prune_report
 
-        t0 = time.perf_counter()
-        raw_fp = plan.fingerprint()
-        raw_lits = ordered_lits(P.all_exprs(plan))
-        self._ensure_bound(plan)
-        with self.catalog.snapshot() as snap:
-            with tel.span("session.execute", sid=self.sid, mode=self.mode):
+        # Phase spans: session.query holds them all; session.bind is the
+        # plan's fingerprint, literals and snapshot pin; session.execute.run
+        # splits into gathering the inputs, dispatching the program and
+        # waiting for the device; session.fetch brings the result to host.
+        with tel.query_span("session.query", sid=self.sid, mode=self.mode):
+            t0 = time.perf_counter()
+            with tel.span("session.bind", sid=self.sid):
+                raw_fp = plan.fingerprint()
+                raw_lits = ordered_lits(P.all_exprs(plan))
+                self._ensure_bound(plan)
+                snap = self.catalog.snapshot()
+            with snap, tel.span("session.execute", sid=self.sid,
+                                mode=self.mode):
                 e = self._plan_entry(plan, raw_fp, raw_lits, snap)
                 cq, binding = self._variant(e, raw_lits, snap)
                 params = _bind_params(binding, raw_lits)
                 with tel.span("session.execute.run", sid=self.sid):
-                    out = cq.run(snap, params=params)
-                    out = jax.block_until_ready(out)
-        dt = time.perf_counter() - t0
-        tel.inc("session.executes_total", sid=self.sid, mode=self.mode)
-        tel.set_gauge("session.last_execute_seconds", dt, sid=self.sid)
-        self.last_optimized = e.opt
-        self.last_physical = cq.physical
-        self.last_prune_report = prune_report(cq.physical)
-        tel.inc("session.pruned_components_total",
-                self.last_prune_report["pruned"], sid=self.sid)
-        if cq.kind == "scalar":
-            vals = {k: np.asarray(v).item() for k, v in out.items()}
-            return vals if len(vals) > 1 else next(iter(vals.values()))
-        env, mask = out
-        return _materialize(env, mask, cq.kind)
+                    with tel.span("session.execute.gather", sid=self.sid):
+                        tables = cq.gather_tables(snap)
+                    with tel.span("session.execute.dispatch", sid=self.sid):
+                        out = cq.call(tables, params)
+                    with tel.span("session.execute.wait", sid=self.sid):
+                        out = jax.block_until_ready(out)
+            dt = time.perf_counter() - t0
+            tel.inc("session.executes_total", sid=self.sid, mode=self.mode)
+            tel.set_gauge("session.last_execute_seconds", dt, sid=self.sid)
+            self.last_optimized = e.opt
+            self.last_physical = cq.physical
+            self.last_prune_report = prune_report(cq.physical)
+            tel.inc("session.pruned_components_total",
+                    self.last_prune_report["pruned"], sid=self.sid)
+            with tel.span("session.fetch", sid=self.sid):
+                if cq.kind == "scalar":
+                    vals = {k: np.asarray(v).item() for k, v in out.items()}
+                    return vals if len(vals) > 1 \
+                        else next(iter(vals.values()))
+                env, mask = out
+                return _materialize(env, mask, cq.kind)
 
     def explain(self, plan: P.Plan, analyze: bool = False) -> str:
         """The costed physical plan for ``plan``, rendered with per-operator
@@ -862,7 +875,7 @@ class Session:
                 params = _bind_params(binding, raw_lits)
                 tables = cq.gather_tables(snap)
                 t0 = time.perf_counter()
-                out = jax.block_until_ready(cq.fn(tables, params))
+                out = jax.block_until_ready(cq.call(tables, params))
                 jit_seconds = time.perf_counter() - t0
                 measures = profile_physical(cq.physical,
                                             self.exec_context(snap),

@@ -141,6 +141,7 @@ def filter_count(cols: jax.Array, bounds: jax.Array, n_valid,
             grid_spec=grid_spec,
             out_shape=jax.ShapeDtypeStruct((1, 1), jnp.int32),
             interpret=interpret,
+            name="filter_count",
         )(ids, *args)
         return out[0, 0]
     if block_ids is None:
@@ -155,6 +156,7 @@ def filter_count(cols: jax.Array, bounds: jax.Array, n_valid,
             out_specs=_SMEM,                                 # accumulator
             out_shape=jax.ShapeDtypeStruct((1, 1), jnp.int32),
             interpret=interpret,
+            name="filter_count",
         )(*args)
         return out[0, 0]
     assert all(0 <= b < nb for b in block_ids), (block_ids, nb)
@@ -175,5 +177,6 @@ def filter_count(cols: jax.Array, bounds: jax.Array, n_valid,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((1, 1), jnp.int32),
         interpret=interpret,
+        name="filter_count",
     )(jnp.asarray(block_ids, jnp.int32), *args)
     return out[0, 0]

@@ -74,6 +74,7 @@ def merge_join_count(lkeys: jax.Array, rkeys: jax.Array, nl, nr,
         out_specs=_SMEM,
         out_shape=jax.ShapeDtypeStruct((1, 1), jnp.int32),
         interpret=interpret,
+        name="merge_join_count",
     )(jnp.asarray(nl, jnp.int32).reshape(1, 1),
       jnp.asarray(nr, jnp.int32).reshape(1, 1),
       l.reshape(1, -1), r.reshape(1, -1))

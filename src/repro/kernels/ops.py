@@ -13,7 +13,9 @@ Backend selection for the relational kernels: ``backend=None`` takes
 """
 from __future__ import annotations
 
+import contextlib
 import functools
+import threading
 from typing import Optional
 
 import jax
@@ -35,30 +37,61 @@ from repro.runtime import telemetry as tel
 # not per run — cached executables don't re-trace).
 DISPATCH_COUNTS: dict[str, int] = {}
 
+# Where the ``kernel.*`` counts of the kernels being traced go: a compiled
+# query's own record (``recording_launches``), else straight to the registry.
+_RECORD = threading.local()
+
 
 def reset_dispatch_counts() -> None:
     DISPATCH_COUNTS.clear()
+
+
+@contextlib.contextmanager
+def recording_launches(into: dict):
+    """Trace a program with its ``kernel.*`` counts recorded in ``into``
+    (series id -> count) instead of the registry: the caller adds ``into``
+    to the registry once per execution of what it traced. A retrace starts
+    the record afresh."""
+    into.clear()
+    prev = getattr(_RECORD, "into", None)
+    _RECORD.into = into
+    try:
+        yield into
+    finally:
+        _RECORD.into = prev
+
+
+def count_kernel(name: str, value: int = 1, **labels) -> None:
+    """Add to a ``kernel.*`` counter. Inside ``recording_launches`` the count
+    belongs to the program being traced and is added on each execution;
+    outside it the kernel runs eagerly, once, and counts at once."""
+    into = getattr(_RECORD, "into", None)
+    if into is None:
+        tel.inc(name, value, **labels)
+        return
+    key = tel.series_key(name, labels)
+    into[key] = into.get(key, 0) + int(value)
 
 
 def _tick(name: str, grid: Optional[int] = None,
           blocks_total: Optional[int] = None,
           backend: Optional[str] = None,
           interpret: Optional[bool] = None) -> None:
-    """One tick per trace. Mirrors into the telemetry registry with the
-    launch shape: which backend (pallas/xla), interpret vs compiled, and —
-    for the block-skipping kernels — grid size vs the component's physical
-    block count (scanned/skipped in kernel-block units)."""
+    """One tick per trace into ``DISPATCH_COUNTS``, and the launch's
+    ``kernel.*`` counts: which backend (pallas/xla), interpret vs compiled,
+    and — for the block-skipping kernels — grid size vs the component's
+    physical block count (scanned/skipped in kernel-block units)."""
     DISPATCH_COUNTS[name] = DISPATCH_COUNTS.get(name, 0) + 1
     pallas = _use_pallas(backend)
-    tel.inc("kernel.launches_total", kernel=name,
-            backend="pallas" if pallas else "xla",
-            interpret=str(pallas and _resolve_interpret(interpret)).lower())
+    count_kernel("kernel.launches_total", kernel=name,
+                 backend="pallas" if pallas else "xla",
+                 interpret=str(pallas and _resolve_interpret(interpret)).lower())
     if grid is not None:
-        tel.inc("kernel.grid_blocks_total", grid, kernel=name)
+        count_kernel("kernel.grid_blocks_total", grid, kernel=name)
         if blocks_total is not None:
-            tel.inc("kernel.blocks_scanned_total", grid, kernel=name)
-            tel.inc("kernel.blocks_skipped_total", blocks_total - grid,
-                    kernel=name)
+            count_kernel("kernel.blocks_scanned_total", grid, kernel=name)
+            count_kernel("kernel.blocks_skipped_total", blocks_total - grid,
+                         kernel=name)
 
 
 def default_backend() -> str:
@@ -124,9 +157,9 @@ def filter_count(cols, bounds, n_valid, backend: Optional[str] = None,
                  interpret: Optional[bool] = None):
     from repro.kernels.filter_count import BLOCK as _FC_BLOCK
     if block_ids_arr is not None:
-        # per-shard runtime ids (already kernel-block units, -1-padded):
-        # grid length is the padded list; true scanned/skipped telemetry is
-        # accounted host-side by the distributed wrapper, not here.
+        # per-shard ids (already kernel-block units, -1-padded): grid length
+        # is the padded list; true scanned/skipped counts come from the
+        # bound list in the distributed wrapper, not here.
         _tick("filter_count", grid=int(block_ids_arr.shape[0]),
               backend=backend, interpret=interpret)
         if _use_pallas(backend):

@@ -155,6 +155,7 @@ def segment_agg(values: jax.Array, gids: jax.Array, num_groups: int, n_valid,
             grid_spec=grid_spec,
             out_shape=jax.ShapeDtypeStruct((num_groups, c), jnp.float32),
             interpret=interpret,
+            name="segment_agg",
         )(ids, *args)
     if block_ids is None:
         return pl.pallas_call(
@@ -168,6 +169,7 @@ def segment_agg(values: jax.Array, gids: jax.Array, num_groups: int, n_valid,
             out_specs=pl.BlockSpec((num_groups, c), lambda i: (0, 0)),
             out_shape=jax.ShapeDtypeStruct((num_groups, c), jnp.float32),
             interpret=interpret,
+            name="segment_agg",
         )(*args)
     assert all(0 <= b < nb for b in block_ids), (block_ids, nb)
     # grid = surviving blocks; the scalar-prefetched id list feeds the
@@ -187,4 +189,5 @@ def segment_agg(values: jax.Array, gids: jax.Array, num_groups: int, n_valid,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((num_groups, c), jnp.float32),
         interpret=interpret,
+        name="segment_agg",
     )(jnp.asarray(block_ids, jnp.int32), *args)

@@ -70,6 +70,7 @@ def block_topk(scores: jax.Array, mask: jax.Array, n_valid, k: int,
         out_shape=[jax.ShapeDtypeStruct((nb, 1, k), jnp.float32),
                    jax.ShapeDtypeStruct((nb, 1, k), jnp.int32)],
         interpret=interpret,
+        name="block_topk",
     )(jnp.asarray(n_valid, jnp.int32).reshape(1, 1),
       scores.astype(jnp.float32).reshape(1, -1),
       mask.astype(jnp.int32).reshape(1, -1))

@@ -11,18 +11,23 @@ sidecar would scrape from a serving AsterixDB node:
   * **histograms** — latency/size distributions with fixed exponential
     buckets (flush build time, write-stall duration, query phases);
   * **spans**    — lightweight structured traces (name, labels, start,
-    duration, parent) kept in a bounded ring; every finished span also
-    feeds the ``<name>_seconds`` histogram, so phase timers and traces
-    are one call site.
+    duration, parent, query) kept in a bounded ring; every finished span
+    also feeds the ``<name>_seconds`` histogram, so phase timers and traces
+    are one call site. Each span also opens a ``jax.profiler``
+    annotation ``repro/<name>``, so a profiler trace shows the program's
+    phases on the same clock as the device's operations (an annotation
+    with no profiler running costs well under a microsecond). A
+    ``query_span`` starts a query: it draws a sequence number, which every
+    span opened inside it records as ``query``.
 
 Series are labeled: ``inc("kernel.launches_total", kernel="filter_count")``
 creates the series ``kernel.launches_total{kernel=filter_count}``. Label
 sets are expected to be low-cardinality (dataset names, levels, modes).
 
 Overhead contract: ``enabled`` gates everything that costs real time —
-span capture (``perf_counter`` pairs, ring appends) and histogram
-observation are no-ops when disabled. Counters and gauges always record:
-they ARE the engine's operational state (``Session.stats``,
+span capture (``perf_counter`` pairs, ring appends, profiler annotations)
+and histogram observation are no-ops when disabled. Counters and gauges
+always record: they ARE the engine's operational state (``Session.stats``,
 ``Catalog.gc_stats`` and the ingest/compactor mirrors are thin views over
 them), and an increment is one locked dict add. Disable with
 ``set_enabled(False)`` or the ``REPRO_TELEMETRY=0`` environment variable.
@@ -36,6 +41,8 @@ golden tests compare.
 """
 from __future__ import annotations
 
+import bisect
+import itertools
 import json
 import os
 import threading
@@ -76,11 +83,8 @@ class _Histogram:
             self.min = value
         if value > self.max:
             self.max = value
-        for i, le in enumerate(DEFAULT_BUCKETS):
-            if value <= le:
-                self.buckets[i] += 1
-                return
-        self.buckets[-1] += 1
+        # the first bucket whose bound is >= value; past the last, +inf
+        self.buckets[bisect.bisect_left(DEFAULT_BUCKETS, value)] += 1
 
     def snapshot(self, normalize: bool = False) -> dict:
         if normalize:  # timing-dependent fields zeroed, event count kept
@@ -112,20 +116,48 @@ class _NoopSpan:
 
 NOOP_SPAN = _NoopSpan()
 
+# A span's profiler annotation is named ``repro/<span name>``.
+ANNOTATION_PREFIX = "repro/"
+_TRACE_ANNOTATION = None  # jax.profiler.TraceAnnotation, imported on first use
+
+
+def _trace_annotation():
+    global _TRACE_ANNOTATION
+    if _TRACE_ANNOTATION is None:
+        from jax.profiler import TraceAnnotation
+        _TRACE_ANNOTATION = TraceAnnotation
+    return _TRACE_ANNOTATION
+
 
 class Span:
-    __slots__ = ("_registry", "name", "labels", "start", "duration", "parent")
+    # Few calls per span on purpose: a traced window's Python tracer
+    # records every function call, and spans sit on every query's path.
+    __slots__ = ("_registry", "name", "labels", "start", "duration", "parent",
+                 "query", "_ann", "_stack")
 
-    def __init__(self, registry: "MetricsRegistry", name: str, labels: dict):
+    def __init__(self, registry: "MetricsRegistry", name: str, labels: dict,
+                 query: Optional[int] = None):
         self._registry = registry
         self.name = name
         self.labels = labels
         self.start = 0.0
         self.duration = 0.0
         self.parent: Optional[str] = None
+        self.query = query  # set here only on a query's own span
+        self._ann = self._stack = None
 
     def __enter__(self) -> "Span":
-        stack = self._registry._span_stack()
+        tls = self._registry._tls
+        stack = self._stack = tls.__dict__.setdefault("stack", [])
+        annotation = _TRACE_ANNOTATION or _trace_annotation()
+        if self.query is not None:  # a query's span: its id goes in the trace
+            ann = annotation(ANNOTATION_PREFIX + self.name, query=self.query)
+        else:
+            if stack:
+                self.query = stack[-1].query
+            ann = annotation(ANNOTATION_PREFIX + self.name)
+        ann.__enter__()
+        self._ann = ann
         self.parent = stack[-1].name if stack else None
         stack.append(self)
         self.start = time.perf_counter()
@@ -133,7 +165,8 @@ class Span:
 
     def __exit__(self, *exc) -> bool:
         self.duration = time.perf_counter() - self.start
-        stack = self._registry._span_stack()
+        self._ann.__exit__(None, None, None)
+        stack = self._stack
         if stack and stack[-1] is self:
             stack.pop()
         self._registry._finish_span(self)
@@ -149,6 +182,9 @@ class MetricsRegistry:
         self._hists: dict[str, _Histogram] = {}
         self._spans: deque = deque(maxlen=max_spans)
         self._tls = threading.local()
+        self._query_ids = itertools.count(1)
+        # (span name, label items) -> its ``<name>_seconds`` histogram
+        self._span_hists: dict = {}
 
     # -- recording ----------------------------------------------------------
 
@@ -158,6 +194,14 @@ class MetricsRegistry:
         key = series_key(name, labels)
         with self._lock:  # int() keeps numpy scalars out of JSON snapshots
             self._counters[key] = self._counters.get(key, 0) + int(value)
+
+    def inc_series(self, counts: dict) -> None:
+        """Counter adds keyed by ready-made series ids (``series_key``),
+        under one lock: how a compiled query counts the kernel launches
+        of each execution."""
+        with self._lock:
+            for key, value in counts.items():
+                self._counters[key] = self._counters.get(key, 0) + value
 
     def set_gauge(self, name: str, value, **labels) -> None:
         key = series_key(name, labels)
@@ -183,6 +227,14 @@ class MetricsRegistry:
         if not self.enabled:
             return NOOP_SPAN
         return Span(self, name, labels)
+
+    def query_span(self, name: str, **labels):
+        """``span`` for the whole of one query: it draws the query's
+        sequence number, which every span opened inside it records and
+        which its profiler annotation carries."""
+        if not self.enabled:
+            return NOOP_SPAN
+        return Span(self, name, labels, query=next(self._query_ids))
 
     # -- reading ------------------------------------------------------------
 
@@ -235,26 +287,25 @@ class MetricsRegistry:
             self._counters.clear()
             self._gauges.clear()
             self._hists.clear()
+            self._span_hists.clear()
             self._spans.clear()
 
     # -- span plumbing ------------------------------------------------------
 
-    def _span_stack(self) -> list:
-        stack = getattr(self._tls, "stack", None)
-        if stack is None:
-            stack = self._tls.stack = []
-        return stack
-
     def _finish_span(self, span: Span) -> None:
-        record = {"name": span.name, "labels": dict(span.labels),
+        record = {"name": span.name, "labels": span.labels,
                   "start": span.start, "duration": span.duration,
-                  "parent": span.parent}
-        key = series_key(span.name + "_seconds", span.labels)
+                  "parent": span.parent, "query": span.query}
+        which = (span.name, *span.labels.items())
         with self._lock:
             self._spans.append(record)
-            h = self._hists.get(key)
+            h = self._span_hists.get(which)
             if h is None:
-                h = self._hists[key] = _Histogram()
+                key = series_key(span.name + "_seconds", span.labels)
+                h = self._hists.get(key)
+                if h is None:
+                    h = self._hists[key] = _Histogram()
+                self._span_hists[which] = h
             h.observe(span.duration)
 
 
@@ -294,6 +345,10 @@ def observe(name: str, value: float, **labels) -> None:
 
 def span(name: str, **labels):
     return REGISTRY.span(name, **labels)
+
+
+def query_span(name: str, **labels):
+    return REGISTRY.query_span(name, **labels)
 
 
 def counter_value(name: str, **labels):

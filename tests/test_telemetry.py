@@ -369,3 +369,140 @@ def test_kernel_launch_counters():
     assert any("kernel=filter_count" in k for k in launches)
     grid = tel.registry().counters("kernel.grid_blocks_total")
     assert any("kernel=filter_count" in k for k in grid)
+
+
+def test_cached_query_counts_kernel_launches_per_execution():
+    """A repeat of a cached kernel-mode query adds its launches again: the
+    counters count executions, while DISPATCH_COUNTS counts traces."""
+    from repro.kernels import ops
+
+    sess = Session(mode="kernel", enable_index=False)
+    sess.create_dataset("K", _table(8192), dataverse="kr", primary="k")
+    df = AFrame("kr", "K", session=sess)
+    launches = lambda: sum(v for k, v in tel.registry().counters(
+        "kernel.launches_total{").items() if "kernel=filter_count" in k)
+    grid = lambda: tel.counter_value("kernel.grid_blocks_total",
+                                     kernel="filter_count")
+    assert len(df[(df["k"] >= 0) & (df["k"] <= 100)]) == 101
+    cq = next(iter(sess._compiled.values()))
+    per_run = sum(v for k, v in cq.launches.items()
+                  if k.startswith("kernel.launches_total{")
+                  and "kernel=filter_count" in k)
+    assert per_run >= 1
+    l0, g0, d0 = launches(), grid(), dict(ops.DISPATCH_COUNTS)
+    for i in range(3):
+        assert len(df[(df["k"] >= i) & (df["k"] <= 100)]) == 101 - i
+    assert sess.stats["compiles"] == 1  # the same executable, never retraced
+    assert launches() == l0 + 3 * per_run
+    assert grid() > g0
+    assert dict(ops.DISPATCH_COUNTS) == d0
+
+
+def test_eager_kernel_call_counts_once():
+    """Outside a query's trace a kernel call is an eager execution: it
+    counts at once, and a recording collects instead of counting."""
+    import jax.numpy as jnp
+
+    from repro.kernels import ops
+
+    cols = jnp.arange(2048, dtype=jnp.int32).reshape(1, -1)
+    bounds = jnp.asarray([[0, 9]], jnp.int32)
+    key = "kernel.launches_total"
+    before = sum(tel.registry().counters(key + "{").values())
+    assert int(ops.filter_count(cols, bounds, 2048, backend="xla")) == 10
+    assert sum(tel.registry().counters(key + "{").values()) == before + 1
+    rec = {"stale": 1}
+    with ops.recording_launches(rec):
+        ops.filter_count(cols, bounds, 2048, backend="xla")
+    assert sum(tel.registry().counters(key + "{").values()) == before + 1
+    assert "stale" not in rec
+    assert rec[tel.series_key(key, {"kernel": "filter_count",
+                                    "backend": "xla",
+                                    "interpret": "false"})] == 1
+
+
+# -- query phases and the profiler trace ---------------------------------------
+
+
+def test_execute_records_every_phase_under_one_query():
+    sess = Session()
+    sess.create_dataset("Q", _table(), dataverse="ph", primary="k")
+    df = AFrame("ph", "Q", session=sess)
+    len(df[(df["k"] >= 0) & (df["k"] <= 9)])          # compiles
+    df[df["k"] >= 500].head()                          # a frame result
+    n0 = len(tel.registry().spans())
+    assert len(df[(df["k"] >= 2) & (df["k"] <= 9)]) == 8   # cached
+    mine = [s for s in tel.registry().spans()[n0:]
+            if s["labels"].get("sid") == sess.sid]
+    parent = {s["name"]: s["parent"] for s in mine}
+    assert parent == {
+        "session.query": None,
+        "session.bind": "session.query",
+        "session.execute": "session.query",
+        "session.prune": "session.execute",
+        "session.execute.run": "session.execute",
+        "session.execute.gather": "session.execute.run",
+        "session.execute.dispatch": "session.execute.run",
+        "session.execute.wait": "session.execute.run",
+        "session.fetch": "session.query",
+    }
+    ids = {s["query"] for s in mine}
+    assert len(ids) == 1 and None not in ids
+    query = next(s for s in mine if s["name"] == "session.query")
+    for s in mine:  # every phase lies inside the query's own span
+        assert query["start"] <= s["start"]
+        assert s["start"] + s["duration"] <= query["start"] + query["duration"]
+    # the next query draws the next id
+    len(df[(df["k"] >= 3) & (df["k"] <= 9)])
+    nxt = [s["query"] for s in tel.registry().spans("session.query")
+           if s["labels"].get("sid") == sess.sid][-1]
+    assert nxt > ids.pop()
+
+
+class _Annotation:
+    opened: list = []
+
+    def __init__(self, name, **meta):
+        self.name, self.meta = name, meta
+
+    def __enter__(self):
+        _Annotation.opened.append((self.name, self.meta))
+        return self
+
+    def __exit__(self, *exc):
+        _Annotation.opened.append(("/" + self.name, {}))
+
+
+def test_spans_open_profiler_annotations(monkeypatch):
+    monkeypatch.setattr(tel, "_TRACE_ANNOTATION", _Annotation)
+    _Annotation.opened = []
+    r = tel.MetricsRegistry()
+    with r.query_span("q", sid="1"):
+        with r.span("inner", label="not in the annotation"):
+            pass
+    assert _Annotation.opened == [
+        ("repro/q", {"query": 1}), ("repro/inner", {}),
+        ("/repro/inner", {}), ("/repro/q", {})]
+    assert [s["query"] for s in r.spans()] == [1, 1]
+    with r.span("outside"):
+        pass
+    assert r.spans("outside")[0]["query"] is None
+
+
+def test_disabled_registry_opens_no_annotation(monkeypatch):
+    monkeypatch.setattr(tel, "_TRACE_ANNOTATION", _Annotation)
+    _Annotation.opened = []
+    r = tel.MetricsRegistry(enabled=False)
+    assert r.query_span("q") is tel.NOOP_SPAN
+    with r.query_span("q"), r.span("inner"):
+        pass
+    assert _Annotation.opened == [] and r.spans() == []
+
+
+def test_inc_series_adds_ready_made_series():
+    r = tel.MetricsRegistry(enabled=False)  # counters record regardless
+    key = tel.series_key("kernel.launches_total", {"kernel": "x"})
+    r.inc_series({key: 2})
+    r.inc_series({key: 3, "plain": 1})
+    assert r.counter_value("kernel.launches_total", kernel="x") == 5
+    assert r.counter_value("plain") == 1
