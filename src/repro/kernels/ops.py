@@ -27,6 +27,7 @@ from repro.kernels.decode_attention import flash_decode as _flash_decode_pallas
 from repro.kernels.filter_count import _resolve_interpret
 from repro.kernels.filter_count import filter_count as _filter_count
 from repro.kernels.flash_attention import flash_mha_fwd as _flash_fwd_pallas
+from repro.kernels.merge_join import grid_size as _merge_join_grid
 from repro.kernels.merge_join import merge_join_count as _merge_join
 from repro.kernels.segment_agg import segment_agg as _segment_agg
 from repro.kernels.topk_mask import topk_merge as _topk_merge
@@ -222,10 +223,20 @@ def merge_join_count(lkeys, rkeys, nl, nr, backend: Optional[str] = None):
     """Equi-join cardinality over SORTED key columns (valid prefix of length
     nl/nr, +inf-style sentinel padding after). The XLA twin exploits the same
     sortedness contract via binary search — ref.merge_join_count's O(nl·nr)
-    compare matrix is a test oracle, not an execution path."""
-    _tick("merge_join_count", backend=backend)
+    compare matrix is a test oracle, not an execution path.
+
+    The kernel's counts: ``grid`` is one launch's pair capacity and
+    ``blocks_total`` the full (left × right) tile grid, so
+    ``blocks_skipped_total`` is what the overlapping band leaves out. They
+    are fixed when the program is traced; the extra launches of a band
+    wider than one launch (heavy duplicate keys) show only in the device
+    trace, as more ``merge_join_count`` events per call."""
     if _use_pallas(backend):
+        grid, total = _merge_join_grid(lkeys.shape[0], rkeys.shape[0])
+        _tick("merge_join_count", grid=grid, blocks_total=total,
+              backend=backend)
         return _merge_join(lkeys, rkeys, nl, nr)
+    _tick("merge_join_count", backend=backend)
     lo = jnp.searchsorted(rkeys, lkeys, side="left")
     hi = jnp.minimum(jnp.searchsorted(rkeys, lkeys, side="right"), nr)
     lm = jnp.arange(lkeys.shape[0]) < nl

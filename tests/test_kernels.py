@@ -41,15 +41,128 @@ def test_segment_agg_sweep(n, c, g, block, dtype):
     np.testing.assert_allclose(got, want, rtol=1e-5, atol=1e-3)
 
 
-@pytest.mark.parametrize("nl,nr,dom,block", [(500, 700, 50, 128),
-                                             (2048, 2048, 5000, 512),
-                                             (100, 4000, 10, 256)])
-def test_merge_join_sweep(nl, nr, dom, block):
-    l = np.sort(RNG.integers(0, dom, nl)).astype(np.int32)
-    r = np.sort(RNG.integers(0, dom, nr)).astype(np.int32)
-    got = merge_join_count(jnp.asarray(l), jnp.asarray(r), nl - 3, nr - 7, block=block)
-    want = ref.merge_join_count(jnp.asarray(l), jnp.asarray(r), nl - 3, nr - 7)
+I32 = np.iinfo(np.int32)
+
+
+def _sorted_random(n, lo, hi):
+    return np.sort(RNG.integers(lo, hi, n)).astype(np.int32)
+
+
+def _sentinel_tail(valid, n):
+    return np.concatenate([valid, np.full(n - len(valid), I32.max, np.int32)])
+
+
+# Each case: (left keys, right keys, nl, nr, block), both key columns sorted
+# over their valid prefix (the kernel's contract).
+def _random_case(nl, nr, dom, block):
+    return lambda: (_sorted_random(nl, 0, dom), _sorted_random(nr, 0, dom),
+                    nl - 3, nr - 7, block)
+
+
+def _wisconsin_case():
+    # a sorted permutation (unique1) against a random tenth of it
+    n = 20_000
+    sub = np.sort(RNG.choice(n, n // 10, replace=False)).astype(np.int32)
+    return np.arange(n, dtype=np.int32), sub, n, n // 10, 128
+
+
+def _equal_case():
+    return (np.full(1000, 5, np.int32), np.full(900, 5, np.int32),
+            1000, 900, 128)
+
+
+def _dup_runs_case():
+    # runs of 150 and 200 equal keys, so runs cross tile boundaries
+    l = np.repeat(np.arange(0, 40, dtype=np.int32), 150)
+    r = np.repeat(np.arange(10, 50, 2, dtype=np.int32), 200)
+    return l, r, len(l), len(r) - 11, 128
+
+
+def _empty_left_case():
+    return (np.full(300, I32.max, np.int32), _sorted_random(500, 0, 50),
+            0, 500, 128)
+
+
+def _empty_right_case():
+    return (_sorted_random(500, 0, 50), np.full(300, I32.max, np.int32),
+            500, 0, 128)
+
+
+def _sentinel_tail_case():
+    # valid prefixes end mid-tile, then whole tiles of sentinel; a valid
+    # key equal to the sentinel still counts
+    lv = np.concatenate([_sorted_random(298, 0, 400), [I32.max] * 2])
+    rv = np.concatenate([_sorted_random(447, 0, 400), [I32.max] * 3])
+    return (_sentinel_tail(lv.astype(np.int32), 1000),
+            _sentinel_tail(rv.astype(np.int32), 900), 300, 450, 128)
+
+
+def _negative_case():
+    l = np.sort(np.concatenate([[I32.min] * 3, RNG.integers(-600, 600, 900)]))
+    r = np.sort(np.concatenate([[I32.min] * 2, RNG.integers(-600, 600, 700)]))
+    return l.astype(np.int32), r.astype(np.int32), 903, 702, 128
+
+
+MERGE_JOIN_CASES = {
+    "random-500x700": _random_case(500, 700, 50, 128),
+    "random-2048x2048": _random_case(2048, 2048, 5000, 512),
+    "random-100x4000": _random_case(100, 4000, 10, 256),
+    "wisconsin": _wisconsin_case,
+    "all-equal": _equal_case,
+    "dup-runs": _dup_runs_case,
+    "empty-left": _empty_left_case,
+    "empty-right": _empty_right_case,
+    "sentinel-tail": _sentinel_tail_case,
+    "negative": _negative_case,
+}
+
+
+@pytest.mark.parametrize("case,c_max", [
+    *[(c, None) for c in MERGE_JOIN_CASES],
+    ("all-equal", 3),       # 64 band pairs: a loop of 22 launches
+    ("dup-runs", 4),
+])
+def test_merge_join_sweep(case, c_max, monkeypatch):
+    from repro.kernels import merge_join
+
+    if c_max is not None:
+        monkeypatch.setattr(merge_join, "C_MAX", c_max)
+        merge_join_count.clear_cache()
+    l, r, nl, nr, block = MERGE_JOIN_CASES[case]()
+    try:
+        got = merge_join_count(jnp.asarray(l), jnp.asarray(r), nl, nr,
+                               block=block)
+    finally:
+        if c_max is not None:
+            merge_join_count.clear_cache()
+    want = ref.merge_join_count(jnp.asarray(l), jnp.asarray(r), nl, nr)
     assert int(got) == int(want)
+
+
+@pytest.mark.parametrize("case", list(MERGE_JOIN_CASES))
+def test_merge_join_band_is_the_overlapping_valid_tiles(case):
+    """The band's pairs are exactly the (left, right) tile pairs whose valid
+    key ranges meet, by a brute count over every pair."""
+    from repro.kernels.merge_join import band
+
+    l, r, nl, nr, block = MERGE_JOIN_CASES[case]()
+
+    def padded(a):
+        return _sentinel_tail(a, -(-len(a) // block) * block)
+
+    def ranges(a, n):
+        return [(a[s], a[min(s + block, n) - 1]) for s in range(0, n, block)]
+
+    want = {(i, j)
+            for i, (llo, lhi) in enumerate(ranges(l, nl))
+            for j, (rlo, rhi) in enumerate(ranges(r, nr))
+            if llo <= rhi and rlo <= lhi}
+    jlo, w, cum = (np.asarray(a) for a in band(
+        jnp.asarray(padded(l)), jnp.asarray(padded(r)), nl, nr, block))
+    got = {(i, j) for i in range(len(w))
+           for j in range(jlo[i], jlo[i] + w[i])}
+    assert int(cum[-1]) == len(want)
+    assert got == want
 
 
 @pytest.mark.parametrize("n,k,block", [(2048, 5, 512), (4096, 1, 1024),

@@ -398,6 +398,29 @@ def test_cached_query_counts_kernel_launches_per_execution():
     assert dict(ops.DISPATCH_COUNTS) == d0
 
 
+def test_join_records_band_skipped_blocks_per_execution():
+    """A kernel-mode join records the merge join's pair grid against the
+    full tile grid on every execution: the band skips the tile pairs whose
+    key ranges cannot meet."""
+    sess = Session(mode="kernel", kernel_backend="pallas", enable_index=False)
+    sess.create_dataset("L", _table(4096), dataverse="kj", primary="k")
+    sess.create_dataset("R", _table(4096), dataverse="kj", primary="k")
+    left, right = AFrame("kj", "L", session=sess), AFrame("kj", "R", session=sess)
+    series = lambda name: tel.counter_value(name, kernel="merge_join_count")
+    assert len(left.merge(right, left_on="k", right_on="k")) == 4096
+    cq = next(iter(sess._compiled.values()))
+    per_run = {name: cq.launches.get(
+        tel.series_key(name, {"kernel": "merge_join_count"}), 0)
+        for name in ("kernel.grid_blocks_total", "kernel.blocks_skipped_total")}
+    assert per_run["kernel.grid_blocks_total"] > 0
+    assert per_run["kernel.blocks_skipped_total"] > 0
+    before = {name: series(name) for name in per_run}
+    assert len(left.merge(right, left_on="k", right_on="k")) == 4096
+    assert sess.stats["compiles"] == 1
+    for name, n in per_run.items():
+        assert series(name) == before[name] + n
+
+
 def test_eager_kernel_call_counts_once():
     """Outside a query's trace a kernel call is an eager execution: it
     counts at once, and a recording collects instead of counting."""
